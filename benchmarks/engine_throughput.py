@@ -333,18 +333,26 @@ def measure_autotune(layers, cfg, opts, *, batches, depths, reps) -> dict:
 def measure_sharding(*, quick: bool = False, devices: int = 8) -> dict:
     """The mesh-sharded serving scaling curve (the ``sharding`` section).
 
-    JAX fixes its device list at initialisation, so the multi-device sweep
-    cannot run in this (already single-device) process: spawn
-    ``benchmarks/sharding_scaling.py`` with forced host devices and adopt
-    its JSON record verbatim.
+    On a TPU the sweep runs in THIS process over the visible chips: a
+    child could not reach a chip this process already holds.  On the CPU,
+    JAX has fixed this process's device list already, so a child pinned to
+    the CPU (``JAX_PLATFORMS=cpu``) runs ``benchmarks/sharding_scaling.py``
+    on forced host devices and its JSON record is adopted verbatim.
     """
     import subprocess
     import sys
 
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "sharding_scaling.py")
+    here = os.path.dirname(os.path.abspath(__file__))
+    if jax.default_backend() != "cpu":
+        sys.path.insert(0, here)  # sharding_scaling.py sits beside this file
+        from sharding_scaling import QUICK, measure_scaling
+
+        return measure_scaling(**(QUICK if quick else {}))
+
+    script = os.path.join(here, "sharding_scaling.py")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
@@ -586,4 +594,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -75,6 +75,9 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
 
     sys.exit(main())

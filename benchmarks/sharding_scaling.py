@@ -13,9 +13,12 @@ output is independent of band geometry: topologies that force a re-banding
 does not fit the visible devices (or has no legal band decomposition) are
 recorded under ``skipped``, never dropped silently.
 
-JAX must see the devices BEFORE it initialises, so run standalone with
-forced host devices (``engine_throughput.measure_sharding`` spawns this
-script exactly that way):
+On a TPU host the ladder runs over the visible chips
+(``engine_throughput.measure_sharding`` calls :func:`measure_scaling` in
+its own process, which holds the chips).  On the CPU, JAX must see the
+devices BEFORE it initialises, so run standalone with forced host devices
+(``measure_sharding`` spawns this script exactly that way, pinned to the
+CPU):
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         PYTHONPATH=src python benchmarks/sharding_scaling.py --json-only
@@ -36,6 +39,8 @@ from repro.models.abpn import ABPNConfig, init_abpn
 
 # the scaling ladder: single device -> band shards -> replicas x shards
 DEFAULT_SPECS = ((1, 1), (1, 2), (1, 4), (2, 4))
+# --quick: CI smoke sizes
+QUICK = dict(height=48, width=16, frames=2, reps=2)
 
 
 def measure_scaling(
@@ -132,7 +137,7 @@ def main():
     kw = dict(height=args.height, width=args.width, backend=args.backend,
               vertical_policy=args.policy, frames=args.frames, reps=args.reps)
     if args.quick:
-        kw.update(height=48, width=16, frames=2, reps=2)
+        kw.update(QUICK)
     rec = measure_scaling(**kw)
     if args.json_path:
         with open(args.json_path, "w") as f:
@@ -154,4 +159,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

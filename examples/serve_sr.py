@@ -107,8 +107,10 @@ def main():
     ap.add_argument("--frames", type=int, default=8, help="total frames to serve")
     ap.add_argument("--batch", type=int, default=4,
                     help="frames per submitted request")
-    ap.add_argument("--height", type=int, default=120)  # paper: 360
-    ap.add_argument("--width", type=int, default=64)    # paper: 640
+    # the paper's 640x360 -> 1920x1080; on the CPU (interpret-mode
+    # kernels) pass a small size, e.g. --height 60 --width 64
+    ap.add_argument("--height", type=int, default=360)
+    ap.add_argument("--width", type=int, default=640)
     ap.add_argument("--backend", default="kernel",
                     choices=["reference", "tilted", "kernel"])
     ap.add_argument("--precision", default="int8",
@@ -247,4 +249,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -25,7 +25,6 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["int8_ef_allreduce", "make_dp_grad_fn", "init_ef_state"]
@@ -92,9 +91,9 @@ def make_dp_grad_fn(loss_fn, mesh: Mesh, data_axis: str = "data",
             specs_like(ef, rep),
         )
         out_specs = (rep, specs_like(params, rep), specs_like(ef, rep))
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )(params, batch, ef)
 
     return fn

@@ -89,6 +89,7 @@ __all__ = [
     "TuningEntry",
     "TuningDB",
     "RooflinePeaks",
+    "PEAKS_BY_DEVICE_KIND",
     "predict_cost",
     "Candidate",
     "enumerate_candidates",
@@ -127,13 +128,11 @@ def default_db_path() -> str:
 
 def device_kind() -> str:
     """The kind string of device 0 — part of every entry's validity stamp
-    (a schedule tuned on one device class must not steer another)."""
+    (a schedule tuned on one device class must not steer another).  A
+    backend that cannot name its device raises; there is no stand-in."""
     import jax
 
-    try:
-        return str(jax.devices()[0].device_kind)
-    except Exception:
-        return "unknown"
+    return str(jax.devices()[0].device_kind)
 
 
 # ----------------------------------------------------------------------
@@ -364,9 +363,9 @@ class TuningDB:
 class RooflinePeaks:
     """Peak compute/bandwidth + cache budget the predictor ranks against.
 
-    Absolute values barely matter (candidates are compared to EACH OTHER
-    and the measured pass arbitrates); the ratios set where the model
-    places the compute/memory knee and when a band's working set spills.
+    The ratios set where the model places the compute/memory knee and when
+    a band's working set spills; the measured pass arbitrates between
+    candidates.  :meth:`detect` reads :data:`PEAKS_BY_DEVICE_KIND`.
     """
 
     flops_per_s: float
@@ -375,15 +374,26 @@ class RooflinePeaks:
 
     @classmethod
     def detect(cls) -> "RooflinePeaks":
-        import jax
+        kind = device_kind()
+        try:
+            return PEAKS_BY_DEVICE_KIND[kind]
+        except KeyError:
+            raise ValueError(
+                f"no roofline peaks for device_kind {kind!r}; add its "
+                "published peaks to autotune.PEAKS_BY_DEVICE_KIND"
+            ) from None
 
-        if jax.default_backend() == "cpu":
-            # a few-core SIMD CPU: tens of GFLOP/s, tens of GB/s, ~1 MiB
-            # effective per-core L2 for the band working set
-            return cls(5e10, 2e10, 1 << 20)
-        # accelerator class: MXU-ish compute, HBM-ish bandwidth, ~16 MiB
-        # on-chip buffer (the paper's SRAM analogue)
-        return cls(1e13, 8e11, 16 << 20)
+
+# Keyed by ``jax.devices()[0].device_kind``.  A kind not listed is an error.
+PEAKS_BY_DEVICE_KIND = {
+    # Google Cloud "TPU v5e" page: 197 TFLOP/s bf16, 819 GB/s HBM; 128 MiB
+    # of VMEM per TensorCore (jax.experimental.pallas.tpu's chip table).
+    "TPU v5 lite": RooflinePeaks(197e12, 819e9, 128 << 20),
+    # The CPU backend runs the interpret-mode test path only.  Not a
+    # published peak: a ranking guess for a few-core SIMD host (tens of
+    # GFLOP/s and GB/s, ~1 MiB of L2 per core for the band working set).
+    "cpu": RooflinePeaks(5e10, 2e10, 1 << 20),
+}
 
 
 def _layer_channels(layers: Sequence) -> List[Tuple[int, int]]:

@@ -29,7 +29,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.fusion import tilted_fused_band
@@ -165,9 +164,9 @@ def build_sharded_executor(
         )
     fspec = frame_spec(mesh)
     body = functools.partial(_sharded_body, splan)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(P(), fspec), out_specs=fspec,
-        check_rep=False,
+        check_vma=False,
     )
     jitted = jax.jit(mapped)
     in_sharding = NamedSharding(mesh, fspec)

@@ -35,7 +35,7 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, *, tile_cols, band_rows, relu, acc_dtype
     acc = jnp.zeros((R * C, co), acc_dtype)
     for dy in range(3):
         for dx in range(3):
-            patch = jax.lax.dynamic_slice(slab, (dy, dx, 0), (R, C, ci))
+            patch = slab[dy:dy + R, dx:dx + C, :]
             acc = acc + jax.lax.dot(
                 patch.reshape(R * C, ci),
                 w_ref[dy, dx].astype(acc_dtype),

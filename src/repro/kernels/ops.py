@@ -4,8 +4,9 @@ These handle the host-side data marshalling that the accelerator's DMA
 engine performs in the paper: channel padding to TPU-friendly widths,
 building the fresh-column stream, and undoing the output tilt.
 
-``interpret`` defaults to True on CPU backends (kernel body executed in
-Python for validation) and False on TPU (compiled to Mosaic).
+``interpret`` defaults to True on the CPU platform (kernel body executed
+for validation) and False on TPU (compiled to Mosaic); any other platform
+is an error — the kernels are Mosaic TPU kernels.
 """
 
 from __future__ import annotations
@@ -37,7 +38,14 @@ VERTICAL_POLICIES = ("zero", "halo", "replicate")
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() == "cpu"
+    """Interpret on the CPU platform only; a TPU always compiles."""
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels target TPU (or CPU interpret mode); "
+            f"the default platform is {platform!r}"
+        )
+    return platform == "cpu"
 
 
 def _round_up(x: int, m: int) -> int:

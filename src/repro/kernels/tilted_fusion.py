@@ -169,7 +169,9 @@ def kernel_buffers(
         "chp": chp,
         "c0p": c0p,
         "buffers": buffers,
-        "row_bounds_smem_bytes": 2 * 4,  # (1, 2) int32 per grid step
+        # the two int32 bounds a grid step reads; the launch keeps all
+        # bands' bounds, (2B,) int32, in SMEM
+        "row_bounds_smem_bytes": 2 * 4,
         "scratch_elements": (
             buffers["overlap"]["elements"] + buffers["residual"]["elements"]
         ),
@@ -196,7 +198,7 @@ def _conv_tile_mxu(f, w_l, b_l, R: int, C: int, chp: int, acc_dtype, row_policy:
     acc = jnp.zeros((R * C, chp), acc_dtype)
     for dy in range(3):
         for dx in range(3):
-            patch = jax.lax.dynamic_slice(frow, (dy, dx, 0), (R, C, chp))
+            patch = frow[dy:dy + R, dx:dx + C, :]
             acc = acc + jax.lax.dot(
                 patch.reshape(R * C, chp),
                 w_l[dy, dx],
@@ -211,7 +213,7 @@ def tilted_fusion_kernel(
     x_ref,  # (1, R, C, C0p)   fresh input stream slab for tile k
     w_ref,  # (L, 3, 3, Chp, Chp)
     b_ref,  # (L, Chp)
-    rows_ref,  # (1, 2) int32   this band's [valid_lo, valid_hi) row range
+    rows_ref,  # (2B,) int32 SMEM  [valid_lo, valid_hi) of every band, flat
     # outputs
     o_ref,  # (1, R, C, Chp)
     # scratch (persistent across sequential grid steps)
@@ -245,11 +247,14 @@ def tilted_fusion_kernel(
         resid_ref[...] = jnp.zeros_like(resid_ref)
         # overlap slot for F_0 holds input columns [-1, 0]:
         # col -1 is zero padding; col 0 is the band's first real column.
-        first = first_col_ref[0, :, 0, :]
-        overlap_ref[0, :, 1, :c0p] = first.astype(overlap_ref.dtype)
+        # (Slices, not integer indices: Mosaic cannot lower the shape cast
+        # an integer-indexed bf16 store needs.)
+        first = first_col_ref[0]  # (R, 1, C0p)
+        slot = jnp.concatenate([jnp.zeros_like(first), first], axis=1)
+        overlap_ref[0, :, :, :c0p] = slot.astype(overlap_ref.dtype)
         # residual ring: after this tile's shift-append the ring spans input
         # columns [-L+1, C]; pre-place col 0 so it lands at ring index L-1.
-        resid_ref[:, C + L - 1, :] = first.astype(resid_ref.dtype)
+        resid_ref[:, C + L - 1:, :] = first.astype(resid_ref.dtype)
 
     fresh = x_ref[0].astype(cdt)  # (R, C, C0p)
 
@@ -262,7 +267,7 @@ def tilted_fusion_kernel(
     # ---- input slab: 2 overlap columns ++ C fresh columns, pad channels ----
     left0 = overlap_ref[0, :, :, :c0p].astype(cdt)  # (R, 2, C0p)
     f = jnp.concatenate([left0, fresh], axis=1)  # (R, C+2, C0p)
-    overlap_ref[0, :, :, :c0p] = f[:, -2:, :].astype(overlap_ref.dtype)
+    overlap_ref[0, :, :, :c0p] = f[:, C:, :].astype(overlap_ref.dtype)
     f = jnp.pad(f, ((0, 0), (0, 0), (0, chp - c0p)))
 
     col_iota = jax.lax.broadcasted_iota(jnp.int32, (1, C, 1), 1)
@@ -271,7 +276,8 @@ def tilted_fusion_kernel(
         # margin a halo slab carries past the image edge) are re-zeroed
         # after every layer so they behave exactly like SAME padding.
         row_iota = jax.lax.broadcasted_iota(jnp.int32, (R, 1, 1), 0)
-        row_ok = (row_iota >= rows_ref[0, 0]) & (row_iota < rows_ref[0, 1])
+        bnd = pl.program_id(0)
+        row_ok = (row_iota >= rows_ref[2 * bnd]) & (row_iota < rows_ref[2 * bnd + 1])
 
     for l in range(L):
         g = _conv_tile_mxu(
@@ -290,21 +296,27 @@ def tilted_fusion_kernel(
         g = g.astype(cdt)
         if l < L - 1:
             left = overlap_ref[l + 1, :, :, :].astype(cdt)  # (R, 2, Chp)
-            overlap_ref[l + 1, :, :, :] = g[:, -2:, :].astype(overlap_ref.dtype)
             f = jnp.concatenate([left, g], axis=1)  # (R, C+2, Chp)
+            # Store f[:, C:], not g[:, -2:]: the same two columns, but
+            # starting at sublane C, a tile boundary.  Storing g's unaligned
+            # tail aborts the TPU compiler (lower_to_llo "d >> 32 == 0").
+            overlap_ref[l + 1, :, :, :] = f[:, C:, :].astype(overlap_ref.dtype)
         else:
             if add_anchor:
                 # anchor = input cols [kC-L+1, kC-L+C) = the ring's head,
                 # each channel repeated scale^2 times (channel-major),
-                # zero-padded up to Chp so padded channels stay clean.
-                anchor = resid_ref[:, :C, :in_channels].astype(cdt)
-                anchor = jnp.repeat(anchor, anchor_repeats, axis=-1)
-                anchor = jnp.pad(
-                    anchor, ((0, 0), (0, 0), (0, chp - in_channels * anchor_repeats))
+                # zero in the lanes past in_channels * repeats.  Built by
+                # lane broadcasts and selects in fp32: Mosaic lowers
+                # neither a lane ``jnp.repeat`` nor bf16 masks.
+                head = resid_ref[:, :C, :].astype(acc_dtype)
+                lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, chp), 2)
+                anchor = sum(
+                    jnp.where(lane // anchor_repeats == i, head[:, :, i:i + 1], 0.0)
+                    for i in range(in_channels)
                 )
                 # phantom anchor columns must be masked like g's
                 anchor = jnp.where((abs_cols >= 0) & (abs_cols < W), anchor, 0.0)
-                g = g + anchor
+                g = (g.astype(acc_dtype) + anchor).astype(cdt)
             o_ref[0] = g.astype(out_dtype)
 
 
@@ -347,7 +359,9 @@ def tilted_fusion_call(
     mask_rows = row_bounds is not None
     if not mask_rows:  # full-band validity placeholder (kernel ignores it)
         row_bounds = jnp.broadcast_to(jnp.array([0, R], jnp.int32), (B, 2))
-    row_bounds = row_bounds.astype(jnp.int32)
+    # Whole into SMEM, flat: a (1, 2) block of a (B, 2) array breaks the
+    # TPU's (8, 128) block rule, and 1-D SMEM pads least.
+    row_bounds = row_bounds.astype(jnp.int32).reshape(2 * B)
 
     kernel = functools.partial(
         tilted_fusion_kernel,
@@ -373,7 +387,7 @@ def tilted_fusion_call(
             pl.BlockSpec((1, R, C, c0p), lambda bnd, k: (bnd, 0, k, 0)),
             pl.BlockSpec((L, 3, 3, chp, chp), lambda bnd, k: (0, 0, 0, 0, 0)),
             pl.BlockSpec((L, chp), lambda bnd, k: (0, 0)),
-            pl.BlockSpec((1, 2), lambda bnd, k: (bnd, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, R, C, chp), lambda bnd, k: (bnd, 0, k, 0)),
         out_shape=jax.ShapeDtypeStruct((B, R, KC, chp), out_dtype),

@@ -204,8 +204,6 @@ def run_cell(
             if ma is not None:
                 rec["memory"] = _mem_dict(ma)
             ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-                ca = ca[0] if ca else None
             if ca:
                 rec["cost_analysis"] = {
                     "flops": float(ca.get("flops", -1.0)),

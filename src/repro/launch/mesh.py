@@ -34,24 +34,16 @@ SR_REPLICA_AXIS = "replica"
 SR_BAND_AXIS = "bands"
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """``axis_types=Auto`` where supported; older jax (< 0.5) has neither
-    ``jax.sharding.AxisType`` nor the kwarg, and Auto is the default."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape, axes = MULTI_POD if multi_pod else SINGLE_POD
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
     """Arbitrary mesh (tests use small ones, e.g. (2, 4) on 8 host devices)."""
     return jax.make_mesh(
-        tuple(shape), tuple(axes), **_axis_type_kwargs(len(axes))
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )
 
 

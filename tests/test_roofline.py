@@ -55,10 +55,7 @@ def test_parser_vs_cost_analysis_unrolled(subproc):
         f = lambda x, y: (x @ y).sum()
         c = jax.jit(f).lower(a, b).compile()
         got = parse_hlo(c.as_text()).flops
-        ca = c.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-            ca = ca[0]
-        want = ca["flops"]
+        want = c.cost_analysis()["flops"]
         assert abs(got - want) / want < 0.05, (got, want)
         print("OK")
     """, devices=1)

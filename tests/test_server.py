@@ -307,6 +307,21 @@ def test_dispatch_failure_sets_future_exception(monkeypatch):
         np.asarray(server.submit(CLIP[:1]).result()), np.asarray(ok))
 
 
+def test_kernel_compile_failure_fails_request_without_backend_swap(monkeypatch):
+    """A kernel the compiler refuses fails the request; the session never
+    serves it through another backend instead."""
+    from repro.kernels import tilted_fusion
+
+    def refuse(*args, **kwargs):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(tilted_fusion, "tilted_fusion_call", refuse)
+    server, session = make_server(session_kw={"backend": "kernel"})
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        server.submit(CLIP[:2]).result()
+    assert session.cache_stats()["entries"] == []  # nothing compiled instead
+
+
 def test_empty_request_resolves_immediately():
     server, _ = make_server()
     fut = server.submit(jnp.zeros((0, *LR)))
