@@ -225,11 +225,14 @@ def sr_features(plan: SRPlan, layers, frames: jax.Array, packed=None) -> jax.Arr
     """Run the plan's conv-stack backend over a frame batch (no epilogue).
 
     ``layers`` are assumed already numerics-prepared; ``packed`` (kernel
-    backend only) supplies pre-packed launch weights.
+    backend only) supplies pre-packed launch weights.  Its operations
+    carry the ``sr_features`` scope in their HLO metadata, which names
+    them in a profiler trace.
     """
-    if plan.backend == "kernel":
-        return _features_kernel(plan, layers, frames, packed)
-    return _BACKENDS[plan.backend](plan, layers, frames)
+    with jax.named_scope("sr_features"):
+        if plan.backend == "kernel":
+            return _features_kernel(plan, layers, frames, packed)
+        return _BACKENDS[plan.backend](plan, layers, frames)
 
 
 def _execute_stack(
@@ -262,14 +265,17 @@ def sr_epilogue(
     both paths assemble the HR batch from identical features, so any drift
     here would break the sharded bit-exactness guarantee.  Row-block local:
     ``depth_to_space`` maps LR row ``y`` to HR rows ``[y*s, y*s+s)``, so the
-    epilogue can run independently on each row shard.
+    epilogue can run independently on each row shard.  Its operations
+    carry the ``sr_epilogue`` scope in their HLO metadata.
     """
-    # make_anchor broadcasts over the frames axis, depth_to_space is vmapped.
-    out = feats + make_anchor(x, plan.scale)
-    hr = jax.vmap(lambda o: depth_to_space(o, plan.scale))(out)
-    if plan.clip:
-        hr = jnp.clip(hr, 0.0, 1.0)
-    return hr.astype(in_dtype)
+    with jax.named_scope("sr_epilogue"):
+        # make_anchor broadcasts over the frames axis, depth_to_space is
+        # vmapped.
+        out = feats + make_anchor(x, plan.scale)
+        hr = jax.vmap(lambda o: depth_to_space(o, plan.scale))(out)
+        if plan.clip:
+            hr = jnp.clip(hr, 0.0, 1.0)
+        return hr.astype(in_dtype)
 
 
 def _execute(plan: SRPlan, layers, frames: jax.Array) -> jax.Array:

@@ -111,7 +111,8 @@ class SchedRequest:
     # expire_due while the request is still fully queued.
     deadline: Optional[float] = None
     # admission timestamp (time.monotonic()) — end-to-end latency anchor
-    # for the server's degrade policy
+    # for the server's degrade policy, and the start of the queue wait
+    # the server records when the request's first frame launches
     admitted_at: float = 0.0
     # partial-band request (temporal delta serving): the band indices the
     # ``n`` slab rows of ``flat`` correspond to.  None = whole frames.

@@ -329,15 +329,18 @@ class SRFuture:
 
 
 class _Inflight:
-    """One launched dispatch: the async HR handle plus its timing and
-    whether it staged through the session's shared host buffer."""
+    """One launched dispatch: the async HR handle, its launch span's
+    bounds (``t0`` the executor call, ``t1`` its return) and whether it
+    staged through the session's shared host buffer."""
 
-    __slots__ = ("dispatch", "hr", "t0", "used_staging")
+    __slots__ = ("dispatch", "hr", "t0", "t1", "used_staging")
 
-    def __init__(self, dispatch: Dispatch, hr, t0: float, used_staging: bool):
+    def __init__(self, dispatch: Dispatch, hr, t0: float, t1: float,
+                 used_staging: bool):
         self.dispatch = dispatch
         self.hr = hr
         self.t0 = t0
+        self.t1 = t1
         self.used_staging = used_staging
 
 
@@ -603,6 +606,13 @@ class SRServer:
             deadline = time.monotonic() + float(timeout)
         name = self._resolve_model(model)
         session = self._sessions[name]
+        with session.spans.span("submit"):
+            return self._submit(session, name, frames, priority, deadline)
+
+    def _submit(self, session: SRSession, name: str, frames, priority: int,
+                deadline: Optional[float]) -> SRFuture:
+        """``submit``'s body: validation, plan, admission (the ``sr.submit``
+        span)."""
         flat, ndim, lead = session.flatten_request(frames)
         degraded = False
         if self._degrade is not None:
@@ -921,19 +931,23 @@ class SRServer:
             self._run_finished(finished)
             return progress
         self._run_finished(finished)
+        spans = inf.dispatch.session.spans
         error: Optional[BaseException] = None
         try:
-            jax.block_until_ready(inf.hr)  # off-lock device wait
+            with spans.span("device_wait") as wait:
+                jax.block_until_ready(inf.hr)  # off-lock device wait
+            spans.record("device_ready", (wait.t1 - inf.t1) * 1e3)
         except BaseException as e:  # deferred device-side failure
             error = e
-        with self._cv:
-            try:
-                self._finalize_complete(inf, error)
-            finally:
-                self._completing -= 1
-                self._cv.notify_all()
-            finished = self._take_finished()
-        self._run_finished(finished)
+        with spans.span("complete") as complete:
+            with self._cv:
+                try:
+                    self._finalize_complete(inf, error, complete.t0)
+                finally:
+                    self._completing -= 1
+                    self._cv.notify_all()
+                finished = self._take_finished()
+            self._run_finished(finished)
         return True
 
     def _take_finished(self) -> list:
@@ -947,6 +961,11 @@ class SRServer:
 
     def _launch(self, d: Dispatch) -> None:
         session: SRSession = d.session
+        spans = session.spans
+        now = time.monotonic()
+        for t in d.tickets:
+            if t.start == 0:  # the dispatch carries the request's first frame
+                spans.record("queue_wait", (now - t.request.admitted_at) * 1e3)
         try:
             # executor resolution may compile — on a dummy, before the
             # timed dispatch starts, exactly like the pre-server path
@@ -966,15 +985,16 @@ class SRServer:
                     model=d.key[0], replica=getattr(entry, "replica", None)
                 )
             if d.band_subset is not None:
-                slab, bounds = self._assemble_bands(d)
+                with spans.span("assemble"):
+                    slab, bounds = self._assemble_bands(d)
                 used_staging = False
-                t0 = time.perf_counter()
-                hr = entry.fn(slab, bounds)  # async dispatch
+                with spans.span("launch") as launch:
+                    hr = entry.fn(slab, bounds)  # async dispatch
             else:
-                slab, used_staging = self._assemble(d, entry.donates)
-                t0 = time.perf_counter()
-                hr = entry.fn(slab)  # async dispatch: returns immediately
-            session._dispatch_ms.append((time.perf_counter() - t0) * 1e3)
+                with spans.span("assemble"):
+                    slab, used_staging = self._assemble(d, entry.donates)
+                with spans.span("launch") as launch:
+                    hr = entry.fn(slab)  # async dispatch: returns immediately
         except BaseException as e:
             self._fail_dispatch(d, e)
             return
@@ -988,13 +1008,14 @@ class SRServer:
         sid = id(session)
         count = self._session_inflight.get(sid, 0)
         if count == 0:
-            self._window_start[sid] = t0
+            self._window_start[sid] = launch.t0
         self._session_inflight[sid] = count + 1
         session._peak_inflight = max(session._peak_inflight, count + 1)
         self._inflight_frames += d.real
         if used_staging:
             self._staging_busy[sid] = self._staging_busy.get(sid, 0) + 1
-        self._inflight.append(_Inflight(d, hr, t0, used_staging))
+        self._inflight.append(
+            _Inflight(d, hr, launch.t0, launch.t1, used_staging))
 
     def _assemble(self, d: Dispatch, donates: bool):
         """Build the bucket-sized device slab from the dispatch's tickets;
@@ -1072,12 +1093,12 @@ class SRServer:
         return jax.device_put(buf), jax.device_put(bounds)
 
     def _finalize_complete(self, inf: _Inflight,
-                           error: Optional[BaseException]) -> None:
+                           error: Optional[BaseException], now: float) -> None:
         """Bookkeeping for a completed (or device-failed) dispatch — runs
-        under the lock, after the off-lock ``block_until_ready``."""
+        under the lock, after the off-lock ``block_until_ready``; ``now``
+        (``spans.clock()`` seconds) is when completion began."""
         d, session = inf.dispatch, inf.dispatch.session
         sid = id(session)
-        now = time.perf_counter()
         # release the replica's in-flight slot FIRST — device failures must
         # not leave a replica looking permanently loaded
         if d.replica is not None and session._router is not None:
@@ -1091,7 +1112,7 @@ class SRServer:
         if error is not None:
             self._fail_dispatch(d, error)
             return
-        session._complete_ms.append((now - inf.t0) * 1e3)
+        session.spans.record("latency", (now - inf.t0) * 1e3)
         if d.band_subset is None:
             session._frames += d.real
         else:

@@ -87,6 +87,7 @@ from repro.engine.plan import (
     SRPlan,
     check_layer_channels,
 )
+from repro.engine.spans import Spans
 
 __all__ = [
     "SRSession",
@@ -105,6 +106,13 @@ __all__ = [
 #              (blocking, on the serving thread) and persists the winner —
 #              first-request latency pays for every later cold start.
 AUTOTUNE_MODES = ("off", "cached", "full")
+
+# The server's spans and counters that stats() reports as <name>_p50_ms:
+# submit (the caller's submit), queue_wait (admission -> the launch that
+# carries a request's first frame), assemble (staging copy + device_put),
+# device_ready (executor call's return -> result ready), complete
+# (finalize, per-request slicing, future resolution, done-callbacks).
+SPAN_STATS = ("submit", "queue_wait", "assemble", "device_ready", "complete")
 
 
 class StreamStats(dict):
@@ -414,8 +422,10 @@ class SRSession:
         # one host-side staging buffer, reused across ragged tails (keyed
         # by (bucket, frame shape, dtype) — replaced when the shape moves)
         self._staging: Optional[Tuple[tuple, np.ndarray]] = None
-        self._dispatch_ms: List[float] = []
-        self._complete_ms: List[float] = []
+        # host spans and counters of the serving path (engine/spans.py):
+        # "launch" (the executor call) and "latency" (launch -> result
+        # ready) feed the headline stats; the rest feed the *_p50_ms keys
+        self.spans = Spans()
         self._span_s = 0.0
         self._frames = 0
         self._peak_inflight = 0
@@ -1022,13 +1032,11 @@ class SRSession:
         """
         n_real = frames.shape[0] if real_frames is None else real_frames
         entry, _ = self.executor_for(plan, frames.shape[0], frames.dtype)
-        t0 = time.perf_counter()
-        hr = entry.fn(frames)
-        jax.block_until_ready(hr)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        self._dispatch_ms.append(dt_ms)
-        self._complete_ms.append(dt_ms)
-        self._span_s += dt_ms / 1e3
+        with self.spans.span("launch") as launch:
+            hr = entry.fn(frames)
+            jax.block_until_ready(hr)
+        self.spans.record("latency", launch.ms)
+        self._span_s += launch.ms / 1e3
         self._frames += n_real
         self._peak_inflight = max(self._peak_inflight, 1)
         return hr
@@ -1046,9 +1054,9 @@ class SRSession:
     # Introspection
     # ------------------------------------------------------------------
     @property
-    def _lat_ms(self) -> List[float]:
+    def _lat_ms(self):
         """Back-compat alias: the complete-latency series."""
-        return self._complete_ms
+        return self.spans.series("latency")
 
     def cache_stats(self) -> dict:
         """Compile-cache counters plus per-entry compile metadata.
@@ -1093,13 +1101,18 @@ class SRSession:
         never included (both happen inside the cache-miss path, outside
         the timed span).  Percentiles split dispatch (enqueue) from
         complete (result ready); ``fps`` is real frames over the serving
-        wall-clock span, so pipelined overlap shows up as throughput."""
+        wall-clock span, so pipelined overlap shows up as throughput.
+        ``<series>_p50_ms`` are the medians of the server's host spans
+        and counters (:data:`SPAN_STATS`), 0.0 before the first value."""
         if self._temporal_counts["frames"] and "temporal" not in extra:
             extra["temporal"] = self.temporal_stats()
+        for name in SPAN_STATS:
+            v = self.spans.values(name)
+            extra[f"{name}_p50_ms"] = float(np.percentile(v, 50)) if v else 0.0
         return latency_stats(
-            self._complete_ms,
+            self.spans.values("latency"),
             self._frames,
-            dispatch_ms=self._dispatch_ms,
+            dispatch_ms=self.spans.values("launch"),
             total_s=self._span_s,
             peak_inflight=self._peak_inflight,
             **extra,
@@ -1167,8 +1180,7 @@ class SRSession:
         return self._router.stats()
 
     def reset_stats(self) -> None:
-        self._dispatch_ms.clear()
-        self._complete_ms.clear()
+        self.spans.reset()
         self._span_s = 0.0
         self._frames = 0
         self._peak_inflight = 0

@@ -396,4 +396,5 @@ def tilted_fusion_call(
             for shape in scratch_shapes(L, R, C, chp, c0p)
         ],
         interpret=interpret,
+        name="tilted_fusion",
     )(first_col, x_stream, w, b, row_bounds)

@@ -175,7 +175,7 @@ def test_sync_caller_sees_identical_dispatch_and_complete():
     plan = session.plan_for(LR)
     session.serve_batch(plan, jnp.ones((2, *LR)))
     session.serve_batch(plan, jnp.ones((2, *LR)))
-    assert session._dispatch_ms == session._complete_ms
+    assert session.spans.values("launch") == session.spans.values("latency")
     s = session.stats()
     assert s["dispatch_mean_ms"] == s["mean_ms"]
     assert s["dispatch_p50_ms"] == s["p50_ms"]
@@ -187,8 +187,8 @@ def test_pipelined_complete_never_precedes_dispatch():
     both are measured from the same dispatch start."""
     session = small_session(pipeline_depth=2)
     session.upscale(CLIP)  # 4 chunks
-    d = np.asarray(session._dispatch_ms)
-    c = np.asarray(session._complete_ms)
+    d = np.asarray(session.spans.values("launch"))
+    c = np.asarray(session.spans.values("latency"))
     assert d.shape == c.shape == (4,)
     assert (c >= d).all()
     s = session.stats()
