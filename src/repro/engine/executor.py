@@ -50,10 +50,6 @@ from repro.core.fusion import (
 from repro.core.quant import dequantize_layers, quantize_layers
 from repro.engine.plan import SRPlan
 
-# models.abpn only imports engine lazily (inside apply_abpn), so the single
-# tested pixel-shuffle/anchor convention can be shared without a cycle.
-from repro.models.abpn import depth_to_space, make_anchor
-
 __all__ = [
     "prepare_layers",
     "prepare_stack",
@@ -261,20 +257,35 @@ def sr_epilogue(
 ) -> jax.Array:
     """ABPN's residual epilogue: anchor add, pixel shuffle, clip, cast.
 
-    Shared between the single-device executor and the band-sharded one —
-    both paths assemble the HR batch from identical features, so any drift
-    here would break the sharded bit-exactness guarantee.  Row-block local:
-    ``depth_to_space`` maps LR row ``y`` to HR rows ``[y*s, y*s+s)``, so the
-    epilogue can run independently on each row shard.  Its operations
-    carry the ``sr_epilogue`` scope in their HLO metadata.
+    Bitwise ``clip(vmap(depth_to_space)(feats + make_anchor(x, s)))`` (the
+    convention of ``models.abpn``), laid out for the chip.  The HR batch is
+    planar on a TPU (physically N, C, H, W), so every intermediate keeps a
+    pixel axis minor: the anchor add and the clip run on (N, C, dy, dx, H, W),
+    the rows interleave while W is minor, and the columns interleave by a
+    transpose that puts (W, dx) above a minor sH and one back.  A minor
+    sub-pixel or colour axis (size ``s`` or C) would be padded to 128 lanes.
+    Pure data movement around the same add and clip, so NaN and -0.0 pass
+    through as before (a one-hot matmul interleave would spread NaNs).
+
+    Shared between the single-device executor, the partial-band one and the
+    band-sharded one, whose bit-exactness rests on it.  Row-block local: LR
+    row ``y`` maps to HR rows ``[y*s, y*s+s)``, so the epilogue can run
+    independently on each row shard.  Its operations carry the
+    ``sr_epilogue`` scope in their HLO metadata.
     """
+    N, H, W, C = x.shape
+    s = plan.scale
     with jax.named_scope("sr_epilogue"):
-        # make_anchor broadcasts over the frames axis, depth_to_space is
-        # vmapped.
-        out = feats + make_anchor(x, plan.scale)
-        hr = jax.vmap(lambda o: depth_to_space(o, plan.scale))(out)
+        # feats channel c*s*s + dy*s + dx -> (N, C, dy, dx, H, W)
+        f = feats.reshape(N, H, W, C, s, s).transpose(0, 3, 4, 5, 1, 2)
+        out = f + x.transpose(0, 3, 1, 2)[:, :, None, None]
         if plan.clip:
-            hr = jnp.clip(hr, 0.0, 1.0)
+            out = jnp.clip(out, 0.0, 1.0)
+        # rows: (N, C, dx, H, dy, W) -> (N, C, dx, sH, W)
+        out = out.transpose(0, 1, 3, 4, 2, 5).reshape(N, C, s, H * s, W)
+        # columns: (N, C, W, dx, sH) -> (N, C, sW, sH)
+        out = out.transpose(0, 1, 4, 2, 3).reshape(N, C, W * s, H * s)
+        hr = out.transpose(0, 3, 2, 1)  # (N, sH, sW, C), planar on the chip
         return hr.astype(in_dtype)
 
 

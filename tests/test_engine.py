@@ -7,7 +7,13 @@ import pytest
 
 from repro import engine
 from repro.core.fusion import conv_stack_reference
-from repro.models.abpn import ABPNConfig, apply_abpn, init_abpn
+from repro.models.abpn import (
+    ABPNConfig,
+    apply_abpn,
+    depth_to_space,
+    init_abpn,
+    make_anchor,
+)
 
 CFG = ABPNConfig()
 LAYERS = init_abpn(jax.random.PRNGKey(2), CFG)
@@ -126,6 +132,39 @@ def test_halo_single_band_image():
     for i in range(2):
         ref = conv_stack_reference(frames[i], LAYERS)
         np.testing.assert_array_equal(np.asarray(feats[i]), np.asarray(ref))
+
+
+# ----------------------------------------------------------------------
+# HR epilogue
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("clip", [True, False], ids=["clip", "noclip"])
+@pytest.mark.parametrize("width", [21, 128])
+@pytest.mark.parametrize("scale", [2, 3, 4])
+def test_sr_epilogue_bitwise_depth_to_space(scale, width, clip, dtype):
+    """The lane-dense epilogue is bitwise the model's definition: anchor add,
+    ``depth_to_space``, clip, cast — NaN, -0.0 and out-of-range values
+    included."""
+    n, h, c = 2, 5, 3
+    rng = np.random.default_rng(scale * 1000 + width)
+    x = rng.uniform(-0.5, 1.5, (n, h, width, c)).astype(np.float32)
+    feats = rng.normal(0.0, 1.0, (n, h, width, c * scale * scale)).astype(np.float32)
+    x[:, 0], feats[:, 0] = -0.0, -0.0  # -0.0 + -0.0 stays -0.0 unclipped
+    x[:, 1, ::3] = np.nan
+    feats[:, 2, ::5] = np.nan
+    x, feats = jnp.asarray(x, dtype), jnp.asarray(feats, dtype)
+    plan = engine.SRPlan(height=h, width=width, band_rows=h, scale=scale, clip=clip)
+
+    got = jax.jit(lambda a, f: engine.sr_epilogue(plan, a, f, jnp.float32))(x, feats)
+
+    def oracle(a, f):
+        hr = jax.vmap(lambda o: depth_to_space(o, scale))(f + make_anchor(a, scale))
+        return (jnp.clip(hr, 0.0, 1.0) if clip else hr).astype(jnp.float32)
+
+    want = jax.jit(oracle)(x, feats)
+    assert got.shape == want.shape == (n, h * scale, width * scale, c)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
 
 
 # ----------------------------------------------------------------------
