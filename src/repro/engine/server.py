@@ -992,7 +992,7 @@ class SRServer:
                     hr = entry.fn(slab, bounds)  # async dispatch
             else:
                 with spans.span("assemble"):
-                    slab, used_staging = self._assemble(d, entry.donates)
+                    slab, used_staging = self._assemble(d, entry)
                 with spans.span("launch") as launch:
                     hr = entry.fn(slab)  # async dispatch: returns immediately
         except BaseException as e:
@@ -1017,18 +1017,19 @@ class SRServer:
         self._inflight.append(
             _Inflight(d, hr, launch.t0, launch.t1, used_staging))
 
-    def _assemble(self, d: Dispatch, donates: bool):
-        """Build the bucket-sized device slab from the dispatch's tickets;
-        returns ``(slab, used_shared_staging)``.
+    def _assemble(self, d: Dispatch, entry):
+        """Build the bucket-sized device slab from the dispatch's tickets
+        for the executor ``entry``; returns ``(slab, used_shared_staging)``.
 
         All-host tickets go through the session's reused staging buffer
         (zero fresh bucket allocations per ragged dispatch) and one
-        ``jax.device_put`` — unless an in-flight dispatch is still using
-        that buffer (overlapped host dispatches), in which case a fresh
-        buffer keeps the earlier H2D copy safe.  Device tickets use a
-        single fused ``jnp.pad`` or ``jnp.concatenate``.  Under donation
-        the returned slab is always server-owned: a full-cover slice that
-        would hand back a caller's own array object is copied first.
+        host-to-device copy (:meth:`_put`) — unless an in-flight dispatch
+        is still using that buffer (overlapped host dispatches), in which
+        case a fresh buffer keeps the earlier H2D copy safe.  Device
+        tickets use a single fused ``jnp.pad`` or ``jnp.concatenate``.
+        Under donation the returned slab is always server-owned: a
+        full-cover slice that would hand back a caller's own array object
+        is copied first.
         """
         session: SRSession = d.session
         tickets = d.tickets
@@ -1037,7 +1038,8 @@ class SRServer:
             first = tickets[0]
             if len(tickets) == 1 and real == d.bucket:
                 src = first.request.flat
-                return jax.device_put(src[first.start:first.start + first.n]), False
+                return self._put(entry, session.spans,
+                                 src[first.start:first.start + first.n]), False
             frame_shape = first.request.flat.shape[1:]
             dtype = first.request.flat.dtype
             shared = not self._staging_busy.get(id(session), 0)
@@ -1048,7 +1050,7 @@ class SRServer:
             for t in tickets:
                 buf[t.slot:t.slot + t.n] = t.request.flat[t.start:t.start + t.n]
             buf[real:] = 0
-            return jax.device_put(buf), shared
+            return self._put(entry, session.spans, buf), shared
         pieces = [t.request.flat[t.start:t.start + t.n] for t in tickets]
         if len(pieces) == 1:
             chunk = pieces[0]
@@ -1057,7 +1059,7 @@ class SRServer:
             if real < d.bucket:
                 pad = [(0, d.bucket - real)] + [(0, 0)] * (chunk.ndim - 1)
                 return jnp.pad(chunk, pad), False
-            if donates and chunk is tickets[0].request.flat:
+            if entry.donates and chunk is tickets[0].request.flat:
                 # a full-cover slice is the SAME array object in jax;
                 # donating it would consume the caller's buffer
                 chunk = jnp.array(chunk)
@@ -1066,6 +1068,17 @@ class SRServer:
             pieces.append(jnp.zeros((d.bucket - real, *pieces[0].shape[1:]),
                                     pieces[0].dtype))
         return jnp.concatenate(pieces, axis=0), False
+
+    @staticmethod
+    def _put(entry, spans, host: np.ndarray) -> jax.Array:
+        """One host-to-device copy of an assembled slab: onto the default
+        device, or, for a band-sharded executor, straight into its input
+        sharding (``sr.shard_put``), so that each chip receives its own
+        rows and none passes through another chip first."""
+        if entry.in_sharding is None:
+            return jax.device_put(host)
+        with spans.span("shard_put"):
+            return jax.device_put(host, entry.in_sharding)
 
     def _assemble_bands(self, d: Dispatch):
         """Build a band dispatch's ``(slab, bounds)`` device pair.

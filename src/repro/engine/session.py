@@ -113,6 +113,9 @@ AUTOTUNE_MODES = ("off", "cached", "full")
 # device_ready (executor call's return -> result ready), complete
 # (finalize, per-request slicing, future resolution, done-callbacks).
 SPAN_STATS = ("submit", "queue_wait", "assemble", "device_ready", "complete")
+# Reported by mesh sessions only: shard_put (inside assemble, a host slab
+# put straight into the band-sharded executor's input sharding).
+MESH_SPAN_STATS = ("shard_put",)
 
 
 class StreamStats(dict):
@@ -197,6 +200,9 @@ class _CacheEntry:
     # replica index when the entry was compiled by a ReplicaRouter (mesh
     # serving); None for ordinary single-device executors
     replica: Optional[int] = None
+    # the band-sharded executor's input sharding, which the server's
+    # assembler puts host slabs straight into; None: the default device
+    in_sharding: Optional[jax.sharding.Sharding] = None
 
     @property
     def jitted(self):
@@ -1103,10 +1109,13 @@ class SRSession:
         complete (result ready); ``fps`` is real frames over the serving
         wall-clock span, so pipelined overlap shows up as throughput.
         ``<series>_p50_ms`` are the medians of the server's host spans
-        and counters (:data:`SPAN_STATS`), 0.0 before the first value."""
+        and counters (:data:`SPAN_STATS`; those of
+        :data:`MESH_SPAN_STATS` on mesh sessions only), 0.0 before the
+        first value."""
         if self._temporal_counts["frames"] and "temporal" not in extra:
             extra["temporal"] = self.temporal_stats()
-        for name in SPAN_STATS:
+        mesh = MESH_SPAN_STATS if self._router is not None else ()
+        for name in SPAN_STATS + mesh:
             v = self.spans.values(name)
             extra[f"{name}_p50_ms"] = float(np.percentile(v, 50)) if v else 0.0
         return latency_stats(

@@ -180,6 +180,7 @@ class ReplicaRouter:
             stack_key=skey,
             donates=False,
             replica=rep.index,
+            in_sharding=fn.in_sharding,
         )
         ckey = (rep.index, *key)
         self._compile_counts[ckey] = self._compile_counts.get(ckey, 0) + 1

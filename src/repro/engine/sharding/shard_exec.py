@@ -150,10 +150,13 @@ def build_sharded_executor(
 
     ``mesh`` must carry a ``bands`` axis of size ``spec.band_shards`` (a
     replica's :func:`repro.launch.mesh.band_submesh`, or any 1-D bands
-    mesh).  The callable shards input rows over ``bands`` via
-    ``device_put``, runs the jitted shard_map program, and returns the HR
-    batch with the same row sharding (gather with ``np.asarray`` when a
-    host copy is needed).
+    mesh).  The callable runs the jitted shard_map program and returns the
+    HR batch with the same row sharding (gather with ``np.asarray`` when a
+    host copy is needed).  Its input sharding is ``fn.in_sharding``: the
+    server's assembler puts each slab straight into it, each chip
+    receiving its own rows, so the callable's own ``device_put`` is a
+    no-op for such a slab and only places one that arrives elsewhere (a
+    device array, a caller's direct call).
     """
     spec = splan.spec
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -176,6 +179,7 @@ def build_sharded_executor(
         return jitted(stack, frames)
 
     fn.jitted = jitted
+    fn.in_sharding = in_sharding
     fn.donates_frames = False
     fn.mesh = mesh
     fn.sharded_plan = splan
