@@ -96,13 +96,19 @@ def _default_channels(plan) -> List[int]:
 
 def plan_buffer_report(plan, channels: Optional[Sequence[int]] = None) -> dict:
     """The kernel's buffer introspection for this plan's geometry
-    (``kernels.tilted_fusion.kernel_buffers``)."""
+    (``kernels.tilted_fusion.kernel_buffers``).  A plan off the ``kernel``
+    backend has no on-chip scratch of its own (its one-tile sweep is
+    ordinary XLA), so it is reported at the kernel's tile,
+    :data:`~repro.engine.plan.KERNEL_TILE_COLS`: what its band height
+    would need as kernel scratch."""
+    from repro.engine.plan import KERNEL_TILE_COLS  # deferred: no cycle
     from repro.kernels.tilted_fusion import kernel_buffers  # deferred: no jax import cost
 
     return kernel_buffers(
         channels=list(channels) if channels else _default_channels(plan),
         band_rows=plan.band_rows,
-        tile_cols=plan.tile_cols,
+        tile_cols=(plan.tile_cols if plan.backend == "kernel"
+                   else KERNEL_TILE_COLS),
     )
 
 

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.tiling import TileSchedule, make_schedule
+from repro.core.tiling import TileSchedule, make_schedule, single_tile_cols
 
 __all__ = [
     "ConvLayer",
@@ -122,7 +122,7 @@ def _conv_tile(f: jax.Array, layer: ConvLayer, row_pad: str) -> jax.Array:
 def tilted_fused_band(
     x: jax.Array,
     layers: Sequence[ConvLayer],
-    tile_cols: int = 8,
+    tile_cols: Optional[int] = None,
     row_pad: str = "zero",
     row_valid: Optional[Tuple[int, int]] = None,
 ) -> jax.Array:
@@ -131,7 +131,9 @@ def tilted_fused_band(
     Args:
       x: band input, shape ``(R, W, Ch0)``.
       layers: the fused conv stack (L layers).
-      tile_cols: C, the parallelepiped width (paper: 8).
+      tile_cols: C, the parallelepiped width; ``None`` sweeps the band in
+        one tile (:func:`~repro.core.tiling.single_tile_cols`), the
+        engine's default off the ``kernel`` backend.  The ASIC's is 8.
       row_pad: vertical boundary policy *within* the band.
       row_valid: optional ``(lo, hi)`` band-row range that is real image
         content; rows outside it are phantom (e.g. the zero margin a
@@ -151,10 +153,12 @@ def tilted_fused_band(
         layer so that edge effects match SAME padding exactly
         (``tiling.phantom_mask``).
     """
-    if tile_cols < 2:
-        raise ValueError("tile_cols must be >= 2 (overlap hand-off is 2 columns)")
     R, W, C0 = x.shape
     L = len(layers)
+    if tile_cols is None:
+        tile_cols = single_tile_cols(W, L)
+    if tile_cols < 2:
+        raise ValueError("tile_cols must be >= 2 (overlap hand-off is 2 columns)")
     sched = make_schedule(width=W, tile_cols=tile_cols, num_layers=L)
     K, C = sched.num_tiles, tile_cols
     chmax = max_channels(layers)
@@ -249,7 +253,7 @@ def run_banded(
     image: jax.Array,
     layers: Sequence[ConvLayer],
     band_rows: int = 60,
-    tile_cols: int = 8,
+    tile_cols: Optional[int] = None,
     vertical_policy: str = "zero",
 ) -> jax.Array:
     """Tilted layer fusion over a full image, band by band.

@@ -37,6 +37,7 @@ __all__ = [
     "TileSchedule",
     "make_schedule",
     "phantom_mask",
+    "single_tile_cols",
 ]
 
 
@@ -46,7 +47,8 @@ class TileSchedule:
 
     Attributes:
       width: W, image width in columns.
-      tile_cols: C, tile width in columns (paper uses 8).
+      tile_cols: C, tile width in columns (the ASIC's 8; one tile per band,
+        :func:`single_tile_cols`, on the pure-JAX path).
       num_layers: L, number of fused conv layers (paper's ABPN uses 7).
       num_tiles: K, total tiles per band *including* the epilogue tiles that
         flush the last output columns (the final layer's tile is shifted
@@ -182,6 +184,13 @@ def make_schedule(width: int, tile_cols: int, num_layers: int) -> TileSchedule:
     """Build and validate a :class:`TileSchedule`."""
     sched = TileSchedule(width=width, tile_cols=tile_cols, num_layers=num_layers)
     return sched
+
+
+def single_tile_cols(width: int, num_layers: int) -> int:
+    """The narrowest tile, a multiple of 8 columns, that sweeps a band in
+    ONE tile: ``width + num_layers - 1`` columns, the tilted tail included
+    (``num_tiles == 1``)."""
+    return -(-(int(width) + int(num_layers) - 1) // 8) * 8
 
 
 def phantom_mask(col_start: int, num_cols: int, width: int) -> np.ndarray:

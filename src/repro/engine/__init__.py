@@ -80,6 +80,7 @@ from repro.engine.plan import (
     VERTICAL_POLICIES,
     SRPlan,
     derive_band_rows,
+    derive_tile_cols,
     legal_band_rows,
     make_plan,
     shardable_band_rows,
@@ -132,6 +133,7 @@ __all__ = [
     "SRPlan",
     "make_plan",
     "derive_band_rows",
+    "derive_tile_cols",
     "legal_band_rows",
     "AUTOTUNE_MODES",
     "PlanTuner",

@@ -17,13 +17,14 @@ import dataclasses
 import warnings
 from typing import List, Optional, Sequence, Tuple
 
-from repro.core.tiling import TileSchedule, make_schedule
+from repro.core.tiling import TileSchedule, make_schedule, single_tile_cols
 
 __all__ = [
     "SRPlan",
     "make_plan",
     "check_layer_channels",
     "derive_band_rows",
+    "derive_tile_cols",
     "legal_band_rows",
     "shardable_band_rows",
     "BACKENDS",
@@ -39,10 +40,30 @@ VERTICAL_POLICIES = ("zero", "halo", "replicate")
 # other heights derive a legal band height near this (derive_band_rows).
 PREFERRED_BAND_ROWS = 60
 
+# The kernel backend's overlap queue and residual ring are VMEM scratch
+# sized by the tile: the paper's 8-column parallelepiped.
+KERNEL_TILE_COLS = 8
+
 # Below this band height the per-band recompute/boundary overhead dominates
 # (the 3x3 stack's receptive field spans 2L+1 rows); rather than slice a
 # frame into slivers, fall back to a single full-height band.
 MIN_BAND_ROWS = 8
+
+
+def derive_tile_cols(backend: str, width: int, num_layers: int) -> int:
+    """The DEFAULT ``tile_cols`` for a plan's backend and frame width.
+
+    The ``kernel`` backend keeps :data:`KERNEL_TILE_COLS` (its scratch is
+    sized by the tile).  Every other backend sweeps each band in ONE tile
+    (:func:`~repro.core.tiling.single_tile_cols`: ``width + num_layers -
+    1`` columns, the tilted tail included, rounded up to a multiple of 8),
+    so the schedule has ``num_tiles == 1`` and the sweep is a plain chain
+    of band convolutions that XLA fuses and tiles itself; a narrower tile
+    only adds sequential scan steps on a TPU.
+    """
+    if backend == "kernel":
+        return KERNEL_TILE_COLS
+    return single_tile_cols(width, num_layers)
 
 
 def legal_band_rows(
@@ -128,7 +149,9 @@ class SRPlan:
       height/width/in_channels: LR frame shape (H, W, C0).
       num_layers: L, depth of the fused conv stack.
       band_rows: R, rows per band (paper: 60 for 360-row frames).
-      tile_cols: C, parallelepiped width of the tilted sweep (paper: 8).
+      tile_cols: C, parallelepiped width of the tilted sweep; ``None``
+        derives it from the backend and width (:func:`derive_tile_cols`:
+        one tile per band, 8 columns on the ``kernel`` backend).
     Numerics:
       precision: ``fp32`` | ``bf16`` | ``int8`` (int8 = symmetric
         weight quantisation with dequant-on-read, ``core.quant``).
@@ -150,7 +173,7 @@ class SRPlan:
     in_channels: int = 3
     num_layers: int = 7
     band_rows: int = 60
-    tile_cols: int = 8
+    tile_cols: Optional[int] = None
     vertical_policy: str = "zero"
     backend: str = "tilted"
     precision: str = "fp32"
@@ -177,6 +200,9 @@ class SRPlan:
                 f"height {self.height} must be a multiple of "
                 f"band_rows {self.band_rows} for backend {self.backend!r}"
             )
+        if self.tile_cols is None:
+            object.__setattr__(self, "tile_cols", derive_tile_cols(
+                self.backend, self.width, self.num_layers))
         if self.tile_cols < 2:
             raise ValueError(
                 f"tile_cols={self.tile_cols} must be >= 2 "
@@ -249,7 +275,7 @@ class SRPlan:
         *,
         num_layers: int,
         band_rows: int | None = None,
-        tile_cols: int = 8,
+        tile_cols: Optional[int] = None,
         vertical_policy: str = "zero",
         backend: str = "tilted",
         precision: str = "fp32",
@@ -268,7 +294,8 @@ class SRPlan:
         servable without the caller knowing the banding rules.  This is
         what :class:`~repro.engine.session.SRSession` calls per new
         resolution; ``make_plan`` routes through it with an explicit
-        ``band_rows``.
+        ``band_rows``.  ``tile_cols=None`` derives the tile from the
+        backend and the frame width (:func:`derive_tile_cols`).
 
         ``tuner`` (a :class:`~repro.engine.autotune.PlanTuner`) is
         consulted BEFORE the default derivation: if its tuning database
@@ -284,6 +311,8 @@ class SRPlan:
         if len(lr_shape) != 3:
             raise ValueError(f"lr_shape {lr_shape!r} must be (H, W, C)")
         H, W, C = (int(x) for x in lr_shape)
+        if tile_cols is None:
+            tile_cols = derive_tile_cols(backend, W, num_layers)
         degenerate = False
         if band_rows is None:
             if tuner is not None:
@@ -338,7 +367,7 @@ def make_plan(
     lr_shape: Tuple[int, int, int],
     *,
     band_rows: int = 60,
-    tile_cols: int = 8,
+    tile_cols: Optional[int] = None,
     vertical_policy: str = "zero",
     backend: str = "tilted",
     precision: str = "fp32",

@@ -86,6 +86,7 @@ from repro.engine.plan import (
     PREFERRED_BAND_ROWS,
     SRPlan,
     check_layer_channels,
+    derive_tile_cols,
 )
 from repro.engine.spans import Spans
 
@@ -308,7 +309,7 @@ class SRSession:
         backend: str = "tilted",
         precision: str = "fp32",
         vertical_policy: str = "zero",
-        tile_cols: int = 8,
+        tile_cols: Optional[int] = None,
         band_rows: Optional[int] = None,
         preferred_band_rows: int = PREFERRED_BAND_ROWS,
         scale: int = 3,
@@ -643,7 +644,9 @@ class SRSession:
             backend=self.backend, precision=self.precision,
             vertical_policy=self.vertical_policy,
             height=H, width=W, channels=C,
-            num_layers=self.num_layers, tile_cols=self.tile_cols,
+            num_layers=self.num_layers,
+            tile_cols=(self.tile_cols if self.tile_cols is not None
+                       else derive_tile_cols(self.backend, W, self.num_layers)),
             scale=self.scale, clip=self.clip,
             batch=int(batch) if batch else 1,
         )

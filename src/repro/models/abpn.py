@@ -102,7 +102,7 @@ def apply_abpn(
     cfg: ABPNConfig = ABPNConfig(),
     method: str = "reference",
     band_rows: int = 60,
-    tile_cols: int = 8,
+    tile_cols: Optional[int] = None,
     vertical_policy: str = "zero",
 ) -> jax.Array:
     """LR (H, W, in_ch) -> HR (H*scale, W*scale, in_ch).

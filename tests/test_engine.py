@@ -71,6 +71,65 @@ def test_plan_derived_geometry_and_invariants():
     plan.check_invariants()  # full tile/layer hand-off sweep
 
 
+@pytest.mark.parametrize("width", [640, 1280])
+def test_tilted_default_is_one_tile_per_band(width):
+    """Off the kernel backend the tile is derived from the width: the
+    whole tilted sweep, W + L - 1 columns rounded up to 8, in one tile."""
+    plans = [
+        engine.SRPlan(height=360, width=width),
+        engine.SRPlan.from_request((360, width, 3), num_layers=7),
+        engine.make_plan(LAYERS, (360, width, 3)),
+        engine.SRSession(LAYERS, autotune="off").plan_for((360, width, 3)),
+    ]
+    for plan in plans:
+        assert plan.tile_cols == -(-(width + 6) // 8) * 8
+        assert plan.tile_cols % 8 == 0
+        assert plan.schedule.num_tiles == 1
+        plan.check_invariants()
+    assert engine.derive_tile_cols("tilted", width, 7) == plans[0].tile_cols
+
+
+def test_band_sharded_shard_plan_is_one_tile():
+    """Each shard of the 4K band-sharded plan (3 bands of 180 x 1280 rows
+    and columns) sweeps its bands in one tile."""
+    from repro.engine.sharding import MeshSpec, ShardedPlan
+
+    splan = ShardedPlan(
+        engine.SRPlan.from_request((720, 1280, 3), num_layers=7),
+        MeshSpec(replicas=1, band_shards=4),
+    )
+    local = splan.local_plan
+    assert (local.height, local.width, local.num_bands) == (180, 1280, 3)
+    assert local.tile_cols == splan.plan.tile_cols == 1288
+    assert local.schedule.num_tiles == 1
+
+
+@pytest.mark.parametrize("width", [64, 640, 1280])
+def test_kernel_backend_keeps_eight_column_tiles(width):
+    """The kernel's overlap queue is VMEM scratch sized by the tile."""
+    assert engine.SRPlan(height=360, width=width, backend="kernel").tile_cols == 8
+    plan = engine.SRPlan.from_request((360, width, 3), num_layers=7,
+                                      backend="kernel")
+    assert plan.tile_cols == 8
+    assert plan.schedule.num_tiles == -(-(width + 6) // 8)
+    session = engine.SRSession(LAYERS, backend="kernel", autotune="off")
+    assert session.plan_for((360, width, 3)).tile_cols == 8
+
+
+@pytest.mark.parametrize("backend", ["tilted", "kernel"])
+def test_explicit_tile_cols_is_kept(backend):
+    assert engine.SRPlan(height=120, width=64, tile_cols=16,
+                         backend=backend).tile_cols == 16
+    plan = engine.SRPlan.from_request((120, 64, 3), num_layers=7,
+                                      tile_cols=4, backend=backend)
+    assert (plan.tile_cols, plan.schedule.num_tiles) == (4, 18)
+    assert engine.make_plan(LAYERS, (120, 64, 3), tile_cols=8,
+                            backend=backend).tile_cols == 8
+    session = engine.SRSession(LAYERS, backend=backend, tile_cols=12,
+                               autotune="off")
+    assert session.plan_for((120, 64, 3)).tile_cols == 12
+
+
 # ----------------------------------------------------------------------
 # Batched engine == per-frame legacy shim, all backends
 # ----------------------------------------------------------------------

@@ -15,6 +15,7 @@ from repro.core.fusion import (
     run_banded,
     tilted_fused_band,
 )
+from repro.core.tiling import make_schedule
 
 
 def make_layers(key, channels, bias_scale=0.1):
@@ -43,6 +44,7 @@ def test_single_band_bit_exact():
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("one_tile", [False, True], ids=["tiles", "one_tile"])
 @settings(max_examples=15, deadline=None)
 @given(
     width=st.integers(5, 49),
@@ -51,13 +53,30 @@ def test_single_band_bit_exact():
     ch=st.integers(1, 6),
     rows=st.integers(3, 12),
 )
-def test_band_exactness_property(width, tile_cols, depth, ch, rows):
+def test_band_exactness_property(one_tile, width, tile_cols, depth, ch, rows):
+    """Exact against the plain stack at any tile width, including a tile
+    that covers the band's whole tilted sweep (tile_cols >= W + L - 1)."""
+    if one_tile:
+        tile_cols += width + depth - 3  # >= width + depth - 1
+        assert make_schedule(width, tile_cols, depth).num_tiles == 1
     key = jax.random.PRNGKey(width * 131 + tile_cols)
     layers = make_layers(key, [2] + [ch] * depth)
     x = jax.random.uniform(jax.random.PRNGKey(3), (rows, width, 2))
     ref = conv_stack_reference(x, layers)
     til = tilted_fused_band(x, layers, tile_cols=tile_cols)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(til), atol=1e-5)
+
+
+@pytest.mark.parametrize("policy", ["zero", "replicate", "halo"])
+def test_one_tile_matches_eight_column_tiles(policy):
+    """The default one-tile sweep computes what the 8-column sweep does,
+    under every vertical policy."""
+    layers = make_layers(jax.random.PRNGKey(11), [3, 8, 8, 8, 5])
+    img = jax.random.uniform(jax.random.PRNGKey(12), (60, 44, 3))
+    one = run_banded(img, layers, band_rows=30, vertical_policy=policy)
+    eight = run_banded(img, layers, band_rows=30, tile_cols=8,
+                       vertical_policy=policy)
+    np.testing.assert_allclose(np.asarray(one), np.asarray(eight), atol=1e-5)
 
 
 @pytest.mark.slow

@@ -13,6 +13,7 @@ compiles — a program compiled for a described chip cannot be read back.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,17 +90,18 @@ def test_conv3x3_kernel_compiles(one_chip):
 
 
 @functools.cache
-def _serving_program(sharding, backend):
-    """The donated batch-4 serving program at 640x360 -> 1920x1080, compiled
-    for the described chip; callers steer ``default_interpret`` off."""
-    plan = SRPlan.from_request((360, W, 3), num_layers=L, backend=backend)
+def _serving_program(sharding, backend, height=360, width=W, bucket=4):
+    """The donated serving program (default: batch 4 at 640x360 ->
+    1920x1080), compiled for the described chip; callers steer
+    ``default_interpret`` off."""
+    plan = SRPlan.from_request((height, width, 3), num_layers=L, backend=backend)
     stack = jax.eval_shape(lambda l: prepare_stack(plan, l),
                            init_abpn(jax.random.PRNGKey(0)))
     fn = build_stack_executor(plan, stack, donate_frames=True)
     return fn.jitted.lower(
         plan,
         jax.tree_util.tree_map(lambda a: _spec(sharding, a.shape, a.dtype), stack),
-        _spec(sharding, (4, 360, W, 3)),
+        _spec(sharding, (bucket, height, width, 3)),
     ).compile()
 
 
@@ -122,4 +124,17 @@ def test_serving_epilogue_stays_lane_dense(one_chip, backend, monkeypatch):
     4.25 GB."""
     monkeypatch.setattr(ops, "default_interpret", lambda: False)
     compiled = _serving_program(one_chip, backend)
+    assert compiled.memory_analysis().temp_size_in_bytes < EPILOGUE_TEMP_BOUND
+
+
+@pytest.mark.parametrize("height,width,bucket", [(360, W, 4), (180, 2 * W, 1)],
+                         ids=["640x360_bucket4", "4k_shard_bucket1"])
+def test_tilted_serving_program_has_no_scan_loop(one_chip, height, width, bucket):
+    """The tilted backend sweeps each band in one tile, so its serving
+    program holds no ``while`` (an 8-column sweep is an 81-step scan at
+    640 columns, 161 at 1280), and its temporaries stay lane-dense.  The
+    second shape is one chip's shard of the 4K band-sharded cell: 3 bands
+    of 60 rows, 1280 columns."""
+    compiled = _serving_program(one_chip, "tilted", height, width, bucket)
+    assert re.search(r"\bwhile\(", compiled.as_text()) is None
     assert compiled.memory_analysis().temp_size_in_bytes < EPILOGUE_TEMP_BOUND
