@@ -1,23 +1,22 @@
 """Validate committed benchmark records against their schemas.
 
-CI's bench-smoke and load-smoke jobs regenerate quick records and run
+CI's load-smoke and temporal-smoke jobs regenerate quick records and run
 this against both the fresh output and the committed JSON, so schema
-drift (renamed/dropped keys, a missing pipelined-mode entry, a broken
-bit-exactness or SLO guarantee) fails the build instead of silently
-rotting the recorded numbers.
+drift (renamed/dropped keys, a broken bit-exactness or SLO guarantee)
+fails the build instead of silently rotting the recorded numbers.
 
     PYTHONPATH=src python benchmarks/check_bench_schema.py [path ...]
 
-Records are dispatched on their ``bench`` field: ``server_load``
-records (benchmarks/server_load.py) get the load-harness checks,
-``temporal_delta`` records (benchmarks/temporal_delta.py) get the
-delta-serving checks; any other record is assumed to be a
-BENCH_engine.json engine record.
+With no path it checks the committed BENCH_server_load.json and
+BENCH_temporal.json.  Records are dispatched on their ``bench`` field:
+``server_load`` records (benchmarks/server_load.py) get the
+load-harness checks, ``temporal_delta`` records
+(benchmarks/temporal_delta.py) get the delta-serving checks; any other
+record is schema drift.
 
 No third-party schema library: the required key sets live next to the
-producer (``engine_throughput.RECORD_KEYS``,
-``server_load.LOAD_RECORD_KEYS``, ...), so adding a field means
-updating producer and checker in the same place.
+producer (``server_load.LOAD_RECORD_KEYS``, ...), so adding a field
+means updating producer and checker in the same place.
 """
 
 from __future__ import annotations
@@ -28,21 +27,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from engine_throughput import (  # noqa: E402
-    ANALYSIS_KEYS,
-    ANALYSIS_SEVERITY_KEYS,
-    AUTOTUNE_CONFIG_KEYS,
-    AUTOTUNE_KEYS,
-    BATCH_KEYS,
-    MODE_KEYS,
-    PIPELINE_KEYS,
-    RECORD_KEYS,
-    ROOFLINE_KEYS,
-    SERVER_KEYS,
-    SERVER_MODE_KEYS,
-    SHARDING_KEYS,
-    SHARDING_POINT_KEYS,
-)
 from server_load import (  # noqa: E402
     ACCEPTANCE_KEYS,
     CALIBRATION_KEYS,
@@ -64,108 +48,6 @@ def _require(obj: dict, keys, where: str, errors: list) -> None:
     missing = [k for k in keys if k not in obj]
     if missing:
         errors.append(f"{where}: missing keys {missing}")
-
-
-def check_record(rec: dict) -> list:
-    """All schema violations in one record (empty list = valid)."""
-    errors: list = []
-    _require(rec, RECORD_KEYS, "record", errors)
-    for bs, r in rec.get("batch", {}).items():
-        _require(r, BATCH_KEYS, f"batch[{bs}]", errors)
-    pipe = rec.get("pipeline", {})
-    _require(pipe, PIPELINE_KEYS, "pipeline", errors)
-    for mode in ("sync", "pipelined"):
-        _require(pipe.get(mode, {}), MODE_KEYS, f"pipeline.{mode}", errors)
-    _require(rec.get("roofline", {}), ROOFLINE_KEYS, "roofline", errors)
-    if pipe.get("bit_exact") is not True:
-        errors.append(
-            "pipeline.bit_exact must be true — pipelined serving changed "
-            "the output"
-        )
-    if pipe.get("chunks", 0) < 4:
-        errors.append(
-            "pipeline comparison must run on a >= 4-chunk clip "
-            f"(got chunks={pipe.get('chunks')})"
-        )
-    server = rec.get("server", {})
-    _require(server, SERVER_KEYS, "server", errors)
-    for mode in ("solo", "coalesced"):
-        _require(server.get(mode, {}), SERVER_MODE_KEYS,
-                 f"server.{mode}", errors)
-    if server.get("bit_exact") is not True:
-        errors.append(
-            "server.bit_exact must be true — coalesced serving changed a "
-            "request's output"
-        )
-    solo_d = server.get("solo", {}).get("dispatches_per_burst")
-    coal_d = server.get("coalesced", {}).get("dispatches_per_burst")
-    if solo_d is not None and coal_d is not None and coal_d > solo_d:
-        errors.append(
-            "server.coalesced must not dispatch MORE than solo serving "
-            f"(coalesced {coal_d} vs solo {solo_d} per burst)"
-        )
-    tuned = rec.get("autotune", {})
-    _require(tuned, AUTOTUNE_KEYS, "autotune", errors)
-    configs = tuned.get("configs", [])
-    if not configs:
-        errors.append("autotune.configs must list at least one swept config")
-    for i, t in enumerate(configs):
-        _require(t, AUTOTUNE_CONFIG_KEYS, f"autotune.configs[{i}]", errors)
-        # the tuner's hard guarantee: the tuned schedule NEVER regresses
-        # below the default (the default candidate is always measured)
-        sp = t.get("speedup")
-        if sp is not None and sp < 1.0:
-            errors.append(
-                f"autotune.configs[{i}] (batch {t.get('batch')}): tuned "
-                f"schedule regressed below default (speedup {sp} < 1.0)"
-            )
-        frac = t.get("achieved_fraction")
-        if frac is not None and frac <= 0:
-            errors.append(
-                f"autotune.configs[{i}]: achieved_fraction {frac} must be "
-                "positive (roofline prediction or measurement is broken)"
-            )
-    depth = rec.get("pipeline", {}).get("tuned_depth")
-    if depth is not None and not 1 <= depth <= 4:
-        errors.append(f"pipeline.tuned_depth {depth} outside the legal 1..4")
-    analysis = rec.get("analysis", {})
-    _require(analysis, ANALYSIS_KEYS, "analysis", errors)
-    for checker in ("concurrency", "plan", "program"):
-        counts = analysis.get(checker, {})
-        _require(counts, ANALYSIS_SEVERITY_KEYS,
-                 f"analysis.{checker}", errors)
-        n_err = counts.get("error")
-        if n_err is not None and n_err != 0:
-            errors.append(
-                f"analysis.{checker} recorded {n_err} error-level "
-                "finding(s) — the static gate must be clean when a bench "
-                "record is produced"
-            )
-    if analysis and analysis.get("clean") is not True:
-        errors.append(
-            "analysis.clean must be true — perf numbers from a tree that "
-            "violates its own static invariants are not comparable"
-        )
-    sharding = rec.get("sharding", {})
-    _require(sharding, SHARDING_KEYS, "sharding", errors)
-    points = sharding.get("points", [])
-    if not points:
-        errors.append("sharding.points must hold at least one scaling point")
-    for i, p in enumerate(points):
-        _require(p, SHARDING_POINT_KEYS, f"sharding.points[{i}]", errors)
-        if p.get("bit_exact") is not True:
-            errors.append(
-                f"sharding.points[{i}] "
-                f"({p.get('replicas')}x{p.get('band_shards')}): bit_exact "
-                "must be true — sharded execution changed the output"
-            )
-    if points and not any(p.get("devices", 0) > 1 for p in points):
-        errors.append(
-            "sharding.points must include at least one multi-device "
-            "topology — a 1-device-only curve proves nothing about the "
-            "sharded executor"
-        )
-    return errors
 
 
 def check_server_load(rec: dict) -> list:
@@ -297,7 +179,8 @@ def check_temporal(rec: dict) -> list:
 
 def main(argv) -> int:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    paths = argv[1:] or [os.path.join(root, "BENCH_engine.json")]
+    paths = argv[1:] or [os.path.join(root, name) for name in
+                         ("BENCH_server_load.json", "BENCH_temporal.json")]
     status = 0
     for path in paths:
         with open(path) as f:
@@ -337,29 +220,10 @@ def main(argv) -> int:
                       f"panning reuse {pan['reuse_ratio']}, "
                       f"bit_exact={acc['all_bit_exact']})")
             continue
-        errors = check_record(rec)
-        if errors:
-            status = 1
-            print(f"{path}: SCHEMA DRIFT")
-            for e in errors:
-                print(f"  - {e}")
-        else:
-            tuned_best = max(
-                (t["speedup"] for t in rec["autotune"]["configs"]),
-                default=0.0,
-            )
-            shard_best = max(
-                (p["scaling"] for p in rec["sharding"]["points"]
-                 if p["devices"] > 1),
-                default=0.0,
-            )
-            print(f"{path}: ok "
-                  f"(pipelined x{rec['pipeline']['speedup']} vs sync, "
-                  f"tuned_depth={rec['pipeline']['tuned_depth']}, "
-                  f"coalesced x{rec['server']['speedup']} vs solo, "
-                  f"autotune best x{tuned_best}, "
-                  f"sharded best x{shard_best} vs 1 device, "
-                  f"bit_exact={rec['pipeline']['bit_exact']})")
+        status = 1
+        print(f"{path}: SCHEMA DRIFT")
+        print(f"  - unknown bench {rec.get('bench')!r} (expected "
+              "'server_load' or 'temporal_delta')")
     return status
 
 

@@ -19,7 +19,6 @@ if _ROOT not in sys.path:
 def main() -> None:
     from benchmarks import (
         bandwidth_reduction,
-        engine_throughput,
         kernel_micro,
         psnr_penalty,
         table1_throughput,
@@ -28,7 +27,7 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     modules = [table1_throughput, table2_buffers, bandwidth_reduction,
-               psnr_penalty, kernel_micro, engine_throughput]
+               psnr_penalty, kernel_micro]
     for mod in modules:
         for name, us, derived in mod.rows():
             print(f'{name},{us:.1f},"{derived}"')

@@ -113,8 +113,7 @@ class Workload:
         self.shapes = dict(shapes)
         self.max_bucket = max_bucket
         self.sessions = {
-            name: SRSession(layers, backend="tilted", autotune="off",
-                            max_bucket=max_bucket)
+            name: SRSession(layers, backend="tilted", max_bucket=max_bucket)
             for name in shapes
         }
         self.pools = {}
@@ -364,10 +363,8 @@ def run_fault_injection(work: Workload) -> dict:
     # sessions' stats, and max_bucket=2 pins one request per dispatch.
     # References come from a SEPARATE clean session — upscale() would
     # lazily bind an embedded server to whichever session it runs on.
-    session = SRSession(work.layers, backend="tilted", autotune="off",
-                        max_bucket=2)
-    ref_session = SRSession(work.layers, backend="tilted", autotune="off",
-                            max_bucket=2)
+    session = SRSession(work.layers, backend="tilted", max_bucket=2)
+    ref_session = SRSession(work.layers, backend="tilted", max_bucket=2)
     refs = []
     clips = []
     key = jax.random.PRNGKey(7)

@@ -13,12 +13,9 @@ output is independent of band geometry: topologies that force a re-banding
 does not fit the visible devices (or has no legal band decomposition) are
 recorded under ``skipped``, never dropped silently.
 
-On a TPU host the ladder runs over the visible chips
-(``engine_throughput.measure_sharding`` calls :func:`measure_scaling` in
-its own process, which holds the chips).  On the CPU, JAX must see the
-devices BEFORE it initialises, so run standalone with forced host devices
-(``measure_sharding`` spawns this script exactly that way, pinned to the
-CPU):
+On a TPU host the ladder runs over the visible chips.  On the CPU, JAX
+must see the devices BEFORE it initialises, so run it with forced host
+devices:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         PYTHONPATH=src python benchmarks/sharding_scaling.py --json-only
@@ -78,8 +75,7 @@ def measure_scaling(
         mesh_kw = {} if needed == 1 else {"mesh": (replicas, band_shards)}
         session = SRSession(
             layers, backend=backend, precision=precision,
-            vertical_policy=vertical_policy, scale=cfg.scale,
-            autotune="off", **mesh_kw,
+            vertical_policy=vertical_policy, scale=cfg.scale, **mesh_kw,
         )
         out = np.asarray(session.upscale(clip))  # compile pass
         if want is None:
@@ -121,8 +117,7 @@ def main():
     ap.add_argument("--quick", action="store_true",
                     help="CI smoke sizes: tiny clip, 2 reps")
     ap.add_argument("--json-only", action="store_true",
-                    help="emit ONLY the JSON record on stdout (for the "
-                         "engine_throughput parent process)")
+                    help="emit ONLY the JSON record on stdout")
     ap.add_argument("--json-path", default=None)
     ap.add_argument("--height", type=int, default=120)
     ap.add_argument("--width", type=int, default=64)

@@ -148,7 +148,7 @@ def measure(*, quick: bool, seed: int) -> dict:
     cfg = ABPNConfig()
     layers = init_abpn(jax.random.PRNGKey(seed), cfg)
     session = SRSession(layers, backend="tilted", vertical_policy=policy,
-                        band_rows=BAND_ROWS, autotune="off")
+                        band_rows=BAND_ROWS)
     clips = make_clips(shape, frames, BAND_ROWS, seed)
     with SRServer({"abpn_x3": session}) as server:
         results = [run_clip(session, server, name, clip)

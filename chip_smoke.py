@@ -131,8 +131,7 @@ def open_server(backend: str, precision: str, seed: int, **kw):
     from repro.engine import SRServer
 
     return SRServer.open(MODEL, backend=backend, precision=precision,
-                         autotune="off", max_bucket=REQUEST_FRAMES, seed=seed,
-                         **kw)
+                         max_bucket=REQUEST_FRAMES, seed=seed, **kw)
 
 
 def check_hr(tag: str, outs, refs, tol: float, why: str) -> bool:

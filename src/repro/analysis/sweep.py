@@ -9,13 +9,11 @@ This is what ``python -m repro.analysis`` and the benchmark's
   backend x vertical-policy x precision grid at the paper's design
   point (ABPN, 360-row frames, 60-row bands) — no compilation.
 * :func:`sweep_programs` — compile small representative sessions
-  (tilted fp32/bf16/int8 + the reference oracle, ``autotune="off"`` so
-  the tuning DB is never touched) and audit every cached executor's
-  jaxpr/HLO.
+  (tilted fp32/bf16/int8 + the reference oracle) and audit every cached
+  executor's jaxpr/HLO.
 
 :func:`analysis_report` bundles the outcome as per-checker severity
-counts plus a ``clean`` verdict — the shape ``BENCH_engine.json``
-records and ``check_bench_schema.py`` validates.
+counts plus a ``clean`` verdict.
 """
 
 from __future__ import annotations
@@ -100,7 +98,6 @@ def sweep_programs(
             "abpn_x3",
             backend=backend,
             precision=precision,
-            autotune="off",
             cache_capacity=4,
         )
         session.upscale(frame)  # populate the cache: one real compile

@@ -23,12 +23,15 @@ weights are prepared once per session into a device-resident
 flight (double buffering), and executors can donate the frame slab back to
 XLA (``donate_frames``).
 
-Schedules are TUNED, not hard-coded: the autotuner (autotune.py) sweeps
-the legal (band_rows, pipeline_depth, bucket policy) space per
-configuration — roofline-pruned, then compiled and measured — and
-persists winners in a JSON :class:`TuningDB`; sessions consult it on
-cold start (``SRSession.open(..., autotune="off"|"cached"|"full")``,
-``session.tuning_stats()``).
+Schedules are DERIVED, in one place (plan.py), from the request shape
+and the session's settings — no state is read from disk.  The band
+height is the largest divisor of the frame height at or below the
+paper's 60 rows (``derive_band_rows``; one full-height band when only
+slivers divide it), drawn from ``legal_band_rows`` and, on a mesh, the
+first that splits over the band shards (``shardable_band_rows``); the
+tile is one per band, or 8 columns on the ``kernel`` backend
+(``derive_tile_cols``); batches round up to a power of two under
+``max_bucket`` (``session.bucket_batch``); ``pipeline_depth`` is 2.
 
 Serving is MESH-AWARE (sharding/): ``SRSession.open(..., mesh=(R, S))``
 band-shards every executor over a ``bands`` device axis (``shard_map`` +
@@ -54,20 +57,12 @@ a pinned session; ``models.abpn.apply_abpn(method=...)`` is an older shim
 over ``run``.
 """
 
-from repro.engine.autotune import (
-    PlanTuner,
-    TuningDB,
-    TuningEntry,
-    TuningKey,
-    tune,
-)
 from repro.engine.executor import (
     PreparedStack,
     build_executor,
     build_stack_executor,
     executor_artifacts,
     output_spec,
-    plan_cost,
     prepare_layers,
     prepare_stack,
     run,
@@ -99,7 +94,6 @@ from repro.engine.server import (
     SRServer,
 )
 from repro.engine.session import (
-    AUTOTUNE_MODES,
     PlanCache,
     SRSession,
     StreamStats,
@@ -135,12 +129,6 @@ __all__ = [
     "derive_band_rows",
     "derive_tile_cols",
     "legal_band_rows",
-    "AUTOTUNE_MODES",
-    "PlanTuner",
-    "TuningDB",
-    "TuningEntry",
-    "TuningKey",
-    "tune",
     "BACKENDS",
     "PRECISIONS",
     "VERTICAL_POLICIES",
@@ -148,7 +136,6 @@ __all__ = [
     "build_stack_executor",
     "executor_artifacts",
     "output_spec",
-    "plan_cost",
     "prepare_layers",
     "prepare_stack",
     "PreparedStack",

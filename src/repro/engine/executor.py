@@ -59,7 +59,6 @@ __all__ = [
     "build_band_executor",
     "executor_artifacts",
     "output_spec",
-    "plan_cost",
     "run",
     "sr_epilogue",
     "sr_features",
@@ -465,46 +464,6 @@ def build_band_executor(
     fn.jitted = jitted
     fn.donates_frames = False
     return fn
-
-
-def plan_cost(
-    plan: SRPlan,
-    layers: Sequence[ConvLayer],
-    batch: int,
-    dtype=jnp.float32,
-    *,
-    stack: Optional[PreparedStack] = None,
-) -> dict:
-    """Roofline terms of the compiled serving executor for one bucket.
-
-    Lowers + compiles ``_execute_stack`` for ``(batch, *lr_shape)`` input
-    and walks the HLO (``roofline.hlo_parse``) for per-call FLOPs and HBM
-    bytes — the software analogue of the paper's DRAM-traffic accounting,
-    reported per frame alongside the weight bytes the PreparedStack keeps
-    resident (the traffic weight hoisting removes from every batch).
-
-    ``stack`` reuses an already-prepared weight stack across calls — the
-    autotuner scores many candidate plans against ONE stack this way,
-    without touching any session's ``PlanCache`` (the jit wrapper here is
-    local to the call; nothing is cached at this layer).
-    """
-    from repro.roofline.hlo_parse import parse_hlo
-
-    if stack is None:
-        stack = prepare_stack(plan, layers)
-    jitted = jax.jit(_execute_stack, static_argnums=0)
-    lowered = jitted.lower(
-        plan, stack, jax.ShapeDtypeStruct((batch, *plan.lr_shape), dtype)
-    )
-    cost = parse_hlo(lowered.compile().as_text())
-    return {
-        "batch": int(batch),
-        "flops": int(cost.flops),
-        "hbm_bytes": int(cost.hbm_bytes),
-        "flops_per_frame": int(cost.flops // batch),
-        "hbm_bytes_per_frame": int(cost.hbm_bytes // batch),
-        "weight_bytes_resident": int(stack.nbytes()),
-    }
 
 
 def executor_artifacts(

@@ -78,8 +78,8 @@ def legal_band_rows(
     (``>= min_rows``), plus the always-legal full-height single band.
     Sorted by distance from ``preferred`` (the paper's 60-row design
     point), ties preferring the divisor ``<= preferred`` — so element 0
-    is a sensible default and the whole list is the autotuner's
-    ``band_rows`` candidate axis.
+    is a sensible default and :func:`shardable_band_rows` takes the first
+    that splits over a mesh's band shards.
     """
     if height <= 0:
         raise ValueError(f"height={height} must be positive")
@@ -283,8 +283,6 @@ class SRPlan:
         clip: bool = True,
         preferred_band_rows: int = PREFERRED_BAND_ROWS,
         validate: bool = True,
-        tuner: Optional[object] = None,
-        bucket: Optional[int] = None,
     ) -> "SRPlan":
         """Build a plan for an arbitrary request shape — the ONE owner of
         the shape -> geometry derivation.
@@ -297,13 +295,6 @@ class SRPlan:
         ``band_rows``.  ``tile_cols=None`` derives the tile from the
         backend and the frame width (:func:`derive_tile_cols`).
 
-        ``tuner`` (a :class:`~repro.engine.autotune.PlanTuner`) is
-        consulted BEFORE the default derivation: if its tuning database
-        holds a measured-best ``band_rows`` for this exact configuration
-        (optionally at batch ``bucket``), that schedule wins; a miss falls
-        back to the unchanged defaults.  The tuner only ever returns
-        numerics-safe overrides (see ``PlanTuner.band_rows_for``).
-
         A derived one-giant-band fallback (a taller-than-preferred frame
         with no legal divisor, e.g. a prime height) is no longer silent:
         it warns and the plan records ``degenerate_bands=True``.
@@ -315,24 +306,9 @@ class SRPlan:
             tile_cols = derive_tile_cols(backend, W, num_layers)
         degenerate = False
         if band_rows is None:
-            if tuner is not None:
-                band_rows = tuner.band_rows_for(
-                    lr_shape=(H, W, C),
-                    num_layers=num_layers,
-                    tile_cols=tile_cols,
-                    vertical_policy=vertical_policy,
-                    backend=backend,
-                    precision=precision,
-                    scale=scale,
-                    clip=clip,
-                    bucket=bucket,
-                )
-            if band_rows is None:
-                band_rows = derive_band_rows(H, preferred_band_rows)
-                # a tuner override is a MEASURED choice, never degenerate;
-                # only the silent default fallback warrants the signal
-                degenerate = _is_degenerate_fallback(H, band_rows,
-                                                     preferred_band_rows)
+            band_rows = derive_band_rows(H, preferred_band_rows)
+            degenerate = _is_degenerate_fallback(H, band_rows,
+                                                 preferred_band_rows)
             if degenerate:
                 warnings.warn(
                     f"height {H} has no band decomposition with bands in "

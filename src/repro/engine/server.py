@@ -471,7 +471,8 @@ class SRServer:
         degrade: Optional[DegradePolicy] = None,
         injector=None,
         seed: int = 0,
-        autotune: Union[str, Mapping[str, str], None] = None,
+        # the benchmark harness still passes it; it goes once that stops
+        tuning_db: Optional[str] = None,
         **session_kwargs,
     ) -> "SRServer":
         """Open a server hosting registered SR models by name.
@@ -479,26 +480,12 @@ class SRServer:
         Each name resolves through ``repro.models.registry``
         (``list_sr_models()`` enumerates them); ``session_kwargs``
         (backend, precision, pipeline_depth, max_bucket, ...) apply to
-        every hosted session.  ``autotune`` sets each session's schedule
-        policy (``"off"`` | ``"cached"`` | ``"full"`` — see
-        ``session.AUTOTUNE_MODES``): a single string applies to every
-        hosted model, a mapping sets it per model name (unnamed models
-        keep the session default).  With no names, hosts the paper's
+        every hosted session.  With no names, hosts the paper's
         ``abpn_x3``.
         """
         names = models or ("abpn_x3",)
-
-        def _kwargs_for(name: str) -> dict:
-            kw = dict(session_kwargs)
-            if isinstance(autotune, Mapping):
-                if name in autotune:
-                    kw["autotune"] = autotune[name]
-            elif autotune is not None:
-                kw["autotune"] = autotune
-            return kw
-
         sessions = {
-            name: SRSession.open(name, seed=seed, **_kwargs_for(name))
+            name: SRSession.open(name, seed=seed, **session_kwargs)
             for name in names
         }
         return cls(
@@ -637,9 +624,7 @@ class SRServer:
             ))
             fut._run_callbacks()
             return fut
-        # the request's frame count keys the tuning-DB lookup on a new
-        # shape (bucket rounding policy is tuned per batch size)
-        plan = session.plan_for(shape, batch_hint=n or None)
+        plan = session.plan_for(shape)
         dtype = session.serving_dtype(flat.dtype)
         if n == 0:
             out = jnp.zeros((0, *plan.hr_shape), session.output_dtype(plan, dtype))

@@ -86,7 +86,6 @@ from repro.engine.plan import (
     PREFERRED_BAND_ROWS,
     SRPlan,
     check_layer_channels,
-    derive_tile_cols,
 )
 from repro.engine.spans import Spans
 
@@ -95,18 +94,7 @@ __all__ = [
     "PlanCache",
     "StreamStats",
     "bucket_batch",
-    "AUTOTUNE_MODES",
 ]
-
-# Cold-start schedule policy (SRSession.open(..., autotune=...)):
-#   "off"    — hard-coded defaults only; the tuning DB is never read.
-#   "cached" — consult the DB per new (shape, batch); a hit applies the
-#              measured-best schedule, a miss falls back to the defaults.
-#              NEVER measures in the serving path (the safe default).
-#   "full"   — like "cached", but a miss runs a small tuning sweep NOW
-#              (blocking, on the serving thread) and persists the winner —
-#              first-request latency pays for every later cold start.
-AUTOTUNE_MODES = ("off", "cached", "full")
 
 # The server's spans and counters that stats() reports as <name>_p50_ms:
 # submit (the caller's submit), queue_wait (admission -> the launch that
@@ -317,11 +305,8 @@ class SRSession:
         cache_capacity: int = 8,
         max_bucket: Optional[int] = None,
         model: Optional[str] = None,
-        pipeline_depth: Optional[int] = None,
+        pipeline_depth: int = 2,
         donate_frames: Optional[bool] = None,
-        autotune: str = "cached",
-        tuner=None,
-        tuning_db: Optional[str] = None,
         strict: bool = False,
         mesh=None,
         route: str = "least_loaded",
@@ -331,22 +316,17 @@ class SRSession:
             raise ValueError("layer stack is empty")
         if max_bucket is not None and max_bucket < 1:
             raise ValueError(f"max_bucket={max_bucket} must be >= 1")
-        if pipeline_depth is not None and pipeline_depth < 1:
+        if pipeline_depth < 1:
             raise ValueError(
                 f"pipeline_depth={pipeline_depth} must be >= 1 "
                 "(1 = blocking, 2 = double-buffered dispatch)"
-            )
-        if autotune not in AUTOTUNE_MODES:
-            raise ValueError(
-                f"autotune {autotune!r} not in {AUTOTUNE_MODES}"
             )
         if cache_capacity < 1:
             raise ValueError(
                 f"cache_capacity={cache_capacity} must be >= 1 "
                 "(the session needs at least one live compiled executor)"
             )
-        # mesh serving: resolve the topology FIRST — it gates autotune
-        # modes and stamps the tuner with the topology descriptor
+        # mesh serving: a trivial (1, 1) mesh serves as a plain session
         self.mesh_spec = None
         self._router = None
         if mesh is not None:
@@ -354,12 +334,6 @@ class SRSession:
 
             spec = MeshSpec.coerce(mesh)
             if not spec.is_trivial:
-                if autotune == "full":
-                    raise ValueError(
-                        'autotune="full" measures single-device schedules '
-                        "and cannot run on a sharded session; tune offline "
-                        'per topology and use "cached" or "off"'
-                    )
                 self.mesh_spec = spec
         self.layers = layers
         self.model = model
@@ -375,40 +349,18 @@ class SRSession:
         # pipeline_depth bounds in-flight chunks per request: 1 = blocking
         # (complete t before dispatching t+1), 2 = double buffering (the
         # paper's ping-pong line buffers), deeper = more latency hiding at
-        # the cost of holding more bucket-sized slabs live.  None = the
-        # tunable default (2) — the autotuner may override it from a
-        # measured DB entry; an EXPLICIT depth is the caller's decision
-        # and is never overridden.
-        self._depth_explicit = pipeline_depth is not None
-        self.pipeline_depth = 2 if pipeline_depth is None else pipeline_depth
-        # schedule autotuning: mode + the DB-backed PlanTuner ("off" keeps
-        # no tuner at all, so the DB file is never even opened)
-        self.autotune = autotune
-        self._tuner = None
-        if autotune != "off":
-            from repro.engine.autotune import PlanTuner  # lazy: no cycle
-
-            self._tuner = tuner if tuner is not None else PlanTuner(
-                path=tuning_db,
-                mesh_shape=(
-                    self.mesh_spec.descriptor if self.mesh_spec else "1x1"
-                ),
-            )
-        self._tuning_counts = {"hits": 0, "misses": 0, "fallbacks": 0,
-                               "applied": 0, "tuned_now": 0}
+        # the cost of holding more bucket-sized slabs live.
+        self.pipeline_depth = pipeline_depth
         # strict=True statically verifies every derived plan
         # (repro.analysis.plan_check) and refuses error-level findings
         # BEFORE anything compiles; degenerate one-giant-band fallbacks
-        # are counted either way and surface in tuning_stats()
+        # are counted either way and surface in cache_stats()
         self.strict = bool(strict)
         self._degenerate_plans = 0
         # per-cache-key compile counter: an entry evicted and re-missed
         # compiles again — the recompile detector (repro.analysis
         # .program_audit) flags keys whose count exceeds one
         self._compile_counts: Dict[tuple, int] = {}
-        # request batch sizes whose measured-best bucket policy is "exact"
-        # (compile the true batch instead of rounding up to a power of two)
-        self._exact_buckets: set = set()
         # donate_frames=None resolves per-backend at first executor build:
         # XLA implements input-output aliasing on accelerators but not CPU
         # (donating there just warns and copies).
@@ -549,21 +501,10 @@ class SRSession:
     def num_layers(self) -> int:
         return len(self.layers)
 
-    def plan_for(
-        self,
-        lr_shape: Tuple[int, int, int],
-        batch_hint: Optional[int] = None,
-    ) -> SRPlan:
-        """The session's plan for one LR frame shape (derived once, memoised).
-
-        ``batch_hint`` (the request's flattened frame count, passed by the
-        server's submit path) keys the tuning-DB lookup: a warm entry for
-        this (shape, batch) applies the measured-best schedule — band
-        decomposition via ``SRPlan.from_request(tuner=...)``, pipeline
-        depth and bucket rounding policy via :meth:`_apply_tuning` — before
-        anything compiles.  With ``autotune="off"`` (or an explicit
-        ``band_rows``) the derivation is exactly the untuned default.
-        """
+    def plan_for(self, lr_shape: Tuple[int, int, int]) -> SRPlan:
+        """The session's plan for one LR frame shape (derived once, memoised
+        — ``SRPlan.from_request``, re-banded to split over a mesh's band
+        shards)."""
         lr_shape = tuple(int(x) for x in lr_shape)
         plan = self._plans.get(lr_shape)
         if plan is not None:
@@ -574,9 +515,6 @@ class SRSession:
                 f"got {lr_shape}"
             )
         check_layer_channels(self.layers, lr_shape[2], self.scale)
-        tuner = self._tuner if self.band_rows is None else None
-        if tuner is not None:
-            self._consult_tuning(lr_shape, batch_hint)
         plan = SRPlan.from_request(
             lr_shape,
             num_layers=self.num_layers,
@@ -588,8 +526,6 @@ class SRSession:
             scale=self.scale,
             clip=self.clip,
             preferred_band_rows=self.preferred_band_rows,
-            tuner=tuner,
-            bucket=batch_hint,
         )
         if self.mesh_spec is not None:
             plan = self._shardable_plan(plan)
@@ -632,90 +568,6 @@ class SRSession:
         errs = _findings.errors(plan_check.verify_plan(plan, **kwargs))
         if errs:
             raise _findings.PlanVerificationError(errs)
-
-    # ------------------------------------------------------------------
-    # Schedule autotuning (engine.autotune)
-    # ------------------------------------------------------------------
-    def _tuning_key(self, lr_shape: tuple, batch: Optional[int]):
-        from repro.engine.autotune import TuningKey
-
-        H, W, C = lr_shape
-        return TuningKey(
-            backend=self.backend, precision=self.precision,
-            vertical_policy=self.vertical_policy,
-            height=H, width=W, channels=C,
-            num_layers=self.num_layers,
-            tile_cols=(self.tile_cols if self.tile_cols is not None
-                       else derive_tile_cols(self.backend, W, self.num_layers)),
-            scale=self.scale, clip=self.clip,
-            batch=int(batch) if batch else 1,
-        )
-
-    def _consult_tuning(self, lr_shape: tuple, batch: Optional[int]) -> None:
-        """DB lookup for a new shape: count the outcome, apply a hit's
-        depth/bucket policy, and — ``autotune="full"`` only — tune NOW on
-        a miss (blocking; the winner persists for every later cold
-        start)."""
-        key = self._tuning_key(lr_shape, batch)
-        entry, kind = self._tuner.lookup(key)
-        self._tuning_counts[
-            {"hit": "hits", "fallback": "fallbacks", "miss": "misses"}[kind]
-        ] += 1
-        if entry is None and self.autotune == "full":
-            entry = self._tune_now(lr_shape, batch)
-        if entry is not None:
-            self._apply_tuning(entry)
-
-    def _apply_tuning(self, entry) -> None:
-        """Adopt a measured-best schedule's session-level knobs.  Band
-        decomposition is applied where plans are built (``from_request``'s
-        tuner hook); depth applies unless the caller pinned one
-        explicitly; an "exact" bucket policy registers the tuned batch so
-        ``_bucket_for`` stops rounding it up."""
-        self._tuning_counts["applied"] += 1
-        if not self._depth_explicit:
-            self.pipeline_depth = int(entry.pipeline_depth)
-        if entry.bucket_policy == "exact":
-            self._exact_buckets.add(int(entry.bucket))
-
-    def _tune_now(self, lr_shape: tuple, batch: Optional[int]):
-        """The ``autotune="full"`` miss path: run a small measured sweep
-        for this (shape, batch) and persist the winner (shallow depth grid
-        + few reps — first-request latency, paid once per DB)."""
-        from repro.engine.autotune import tune
-
-        default_plan = SRPlan.from_request(
-            lr_shape,
-            num_layers=self.num_layers,
-            tile_cols=self.tile_cols,
-            vertical_policy=self.vertical_policy,
-            backend=self.backend,
-            precision=self.precision,
-            scale=self.scale,
-            clip=self.clip,
-            preferred_band_rows=self.preferred_band_rows,
-        )
-        entry = tune(
-            self.layers, default_plan, batch or 1,
-            db=self._tuner.db, depths=(1, 2), chunks=2, reps=1,
-        )
-        self._tuning_counts["tuned_now"] += 1
-        return entry
-
-    def tuning_stats(self) -> dict:
-        """Autotune outcome counters: ``hits`` (exact DB entry),
-        ``fallbacks`` (nearest tuned batch), ``misses``, ``applied``
-        (schedules adopted), ``tuned_now`` (blocking sweeps run by
-        ``autotune="full"``), plus the mode, DB path and the live
-        session-level knobs the tuner controls."""
-        return {
-            "mode": self.autotune,
-            "db_path": self._tuner.db.path if self._tuner else None,
-            **self._tuning_counts,
-            "degenerate_plans": self._degenerate_plans,
-            "pipeline_depth": self.pipeline_depth,
-            "exact_buckets": sorted(self._exact_buckets),
-        }
 
     def _memo_put(self, memo: dict, key, value) -> None:
         """Insert into a memo dict, evicting oldest entries past the cap
@@ -910,12 +762,6 @@ class SRSession:
     def _bucket_for(self, n: int) -> int:
         if self._pinned_bucket is not None:
             return self._pinned_bucket
-        if n in self._exact_buckets and (
-            self.max_bucket is None or n <= self.max_bucket
-        ):
-            # the tuner measured this batch faster compiled exactly than
-            # rounded up (padding waste beats the extra program)
-            return n
         bucket = bucket_batch(n)
         if self.max_bucket is not None:
             # clamp DOWN to the largest power of two within the cap — the
@@ -1075,11 +921,13 @@ class SRSession:
         bucket, serving dtype and measured compile seconds.  ``stacks``
         lists the device-resident prepared weight stacks with their entry
         refcounts, one-time prepare seconds and resident bytes.
+        ``degenerate_plans`` counts derived one-giant-band fallbacks.
         """
         stats = self._cache.stats()
         stats["recompiles"] = sum(
             c - 1 for c in self._compile_counts.values() if c > 1
         )
+        stats["degenerate_plans"] = self._degenerate_plans
         stats["entries"] = [
             {
                 "lr_shape": list(e.plan.lr_shape),

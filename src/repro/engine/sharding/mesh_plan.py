@@ -68,7 +68,7 @@ class MeshSpec:
 
     @property
     def descriptor(self) -> str:
-        """Topology stamp, e.g. ``"2x4"`` — autotune DB validity key."""
+        """Topology stamp, e.g. ``"2x4"`` (``replicas x band_shards``)."""
         return f"{self.replicas}x{self.band_shards}"
 
     @property

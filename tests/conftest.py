@@ -39,12 +39,3 @@ def subproc():
 def rng():
     return jax.random.PRNGKey(0)
 
-
-@pytest.fixture(autouse=True)
-def _hermetic_tuning_db(tmp_path, monkeypatch):
-    """Point the plan-tuning DB at a per-test temp path: sessions default
-    to ``autotune="cached"``, so without this a developer's real
-    ``~/.cache/repro-sr/tuning.json`` could steer schedules mid-test (and
-    tests that tune would pollute it).  Tests that need a specific DB set
-    the env var — or pass ``tuning_db=``/``tuner=`` — themselves."""
-    monkeypatch.setenv("REPRO_SR_TUNING_DB", str(tmp_path / "tuning.json"))

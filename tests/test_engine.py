@@ -79,7 +79,7 @@ def test_tilted_default_is_one_tile_per_band(width):
         engine.SRPlan(height=360, width=width),
         engine.SRPlan.from_request((360, width, 3), num_layers=7),
         engine.make_plan(LAYERS, (360, width, 3)),
-        engine.SRSession(LAYERS, autotune="off").plan_for((360, width, 3)),
+        engine.SRSession(LAYERS).plan_for((360, width, 3)),
     ]
     for plan in plans:
         assert plan.tile_cols == -(-(width + 6) // 8) * 8
@@ -112,7 +112,7 @@ def test_kernel_backend_keeps_eight_column_tiles(width):
                                       backend="kernel")
     assert plan.tile_cols == 8
     assert plan.schedule.num_tiles == -(-(width + 6) // 8)
-    session = engine.SRSession(LAYERS, backend="kernel", autotune="off")
+    session = engine.SRSession(LAYERS, backend="kernel")
     assert session.plan_for((360, width, 3)).tile_cols == 8
 
 
@@ -125,8 +125,7 @@ def test_explicit_tile_cols_is_kept(backend):
     assert (plan.tile_cols, plan.schedule.num_tiles) == (4, 18)
     assert engine.make_plan(LAYERS, (120, 64, 3), tile_cols=8,
                             backend=backend).tile_cols == 8
-    session = engine.SRSession(LAYERS, backend=backend, tile_cols=12,
-                               autotune="off")
+    session = engine.SRSession(LAYERS, backend=backend, tile_cols=12)
     assert session.plan_for((120, 64, 3)).tile_cols == 12
 
 

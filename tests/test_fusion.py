@@ -89,19 +89,24 @@ def test_halo_policy_full_image_exact():
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-5)
 
 
-def test_zero_policy_differs_only_at_band_boundaries():
+@pytest.mark.parametrize("policy", ["zero", "replicate"])
+@pytest.mark.parametrize("band_rows", [15, 30, 45])
+def test_zero_policy_differs_only_at_band_boundaries(policy, band_rows):
+    """A non-halo policy changes only the rows within L of a band edge,
+    whatever the band height."""
     key = jax.random.PRNGKey(7)
     L = 4
     layers = make_layers(key, [3] + [6] * L)
     img = jax.random.uniform(jax.random.PRNGKey(8), (90, 40, 3))
     ref = np.asarray(conv_stack_reference(img, layers))
     out = np.asarray(
-        run_banded(img, layers, band_rows=30, tile_cols=4, vertical_policy="zero")
+        run_banded(img, layers, band_rows=band_rows, tile_cols=4,
+                   vertical_policy=policy)
     )
     diff = np.abs(ref - out).max(axis=(1, 2))
     # interior rows (further than L from any band boundary) must be exact
-    for b0 in (0, 30, 60):
-        interior = slice(b0 + L, b0 + 30 - L)
+    for b0 in range(0, 90, band_rows):
+        interior = slice(b0 + L, b0 + band_rows - L)
         assert diff[interior].max() == 0.0
     # and something must differ at the boundaries (otherwise no trade-off)
     assert diff.max() > 0

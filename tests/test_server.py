@@ -218,6 +218,14 @@ def test_server_open_resolves_registry():
         SRServer.open("espcn_x4")
 
 
+def test_server_open_ignores_tuning_db(tmp_path):
+    path = tmp_path / "tuning.json"
+    with SRServer.open("abpn_x3", layers=LAYERS, tuning_db=str(path)) as server:
+        out = server.submit(np.zeros((2, *LR), np.float32)).result()
+    assert out.shape == (2, 36, 48, 3)
+    assert not path.exists() and list(tmp_path.iterdir()) == []
+
+
 # ----------------------------------------------------------------------
 # Streaming
 # ----------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """SRSession serving API: plan derivation, batch bucketing, PlanCache LRU,
 session/stream parity, empty-clip dtype, warmup dtype.  All fast tier."""
 
+import os
 import warnings
 
 import jax
@@ -14,7 +15,12 @@ except ImportError:  # bare env: deterministic fallback sampler
     from _hypothesis_compat import given, settings, strategies as st
 
 from repro import engine
-from repro.engine.plan import derive_band_rows
+from repro.engine.plan import (
+    SRPlan,
+    derive_band_rows,
+    legal_band_rows,
+    shardable_band_rows,
+)
 from repro.engine.session import PlanCache, bucket_batch
 from repro.models.abpn import ABPNConfig, init_abpn
 from repro.models.registry import get_sr_model, register_sr_model
@@ -61,6 +67,120 @@ def test_derive_band_rows_design_points():
     assert derive_band_rows(64) == 32
     assert derive_band_rows(97) == 97  # prime: one band, no slivers
     assert derive_band_rows(6) == 6
+
+
+def test_legal_band_rows_all_divisors_sorted_by_preference():
+    cands = legal_band_rows(120)
+    assert all(120 % d == 0 for d in cands)
+    assert cands[0] == 60  # nearest the paper's design point
+    assert set(cands) == {8, 10, 12, 15, 20, 24, 30, 40, 60, 120}
+    # distance from preferred is non-decreasing
+    dist = [abs(d - 60) for d in cands]
+    assert dist == sorted(dist)
+
+
+def test_legal_band_rows_prime_height_only_full_band():
+    assert legal_band_rows(127) == [127]
+
+
+def test_derive_band_rows_matches_legacy_semantics():
+    assert derive_band_rows(360) == 60
+    assert derive_band_rows(120) == 60
+    assert derive_band_rows(80) == 40
+    assert derive_band_rows(62) == 31
+    assert derive_band_rows(24) == 24
+    assert derive_band_rows(127) == 127  # prime: one giant band
+
+
+def test_prime_height_warns_and_flags_degenerate():
+    with pytest.warns(RuntimeWarning, match="ONE 127-row band"):
+        plan = SRPlan.from_request((127, 16, 3), num_layers=len(LAYERS))
+    assert plan.degenerate_bands is True
+    assert plan.band_rows == 127
+    # metadata only: equal to the same plan without the flag, same hash
+    twin = SRPlan.from_request((127, 16, 3), num_layers=len(LAYERS),
+                               band_rows=127)
+    assert twin.degenerate_bands is False
+    assert plan == twin and hash(plan) == hash(twin)
+
+
+def test_non_degenerate_heights_do_not_warn():
+    import warnings
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        plan = SRPlan.from_request((120, 16, 3), num_layers=len(LAYERS))
+    assert plan.degenerate_bands is False
+
+
+# LR frames users send (16:9 at 240p..1080p); the 4K cell's 720p frames
+# are served over 4 band shards, and so could 1080p's.
+SERVED = [((240, 426), 1), ((360, 640), 1), ((480, 854), 1),
+          ((540, 960), 1), ((720, 1280), 1), ((1080, 1920), 1),
+          ((720, 1280), 4), ((1080, 1920), 4)]
+
+
+@pytest.mark.parametrize(
+    "hw,band_shards", SERVED,
+    ids=[f"{h}x{w}-band{s}" for (h, w), s in SERVED])
+def test_plan_derivation_at_served_heights(hw, band_shards):
+    """At every served height the derived band is a legal, non-degenerate
+    divisor and the first preference (on a mesh: the first one whose band
+    count splits over the shards), and each band sweeps in one tile."""
+    H, W = hw
+    legal = legal_band_rows(H)
+    if band_shards == 1:
+        derived = SRPlan.from_request((H, W, 3), num_layers=len(LAYERS))
+        assert not derived.degenerate_bands
+        band_rows, first = derived.band_rows, legal[0]
+    else:
+        band_rows = shardable_band_rows(H, band_shards)
+        first = next(d for d in legal if (H // d) % band_shards == 0)
+        assert (H // band_rows) % band_shards == 0
+    assert band_rows in legal and band_rows == first
+    assert not (band_rows == H and H > 60)  # never the one-band fallback
+    plan = SRPlan.from_request((H, W, 3), num_layers=len(LAYERS),
+                               band_rows=band_rows)
+    assert plan.schedule.num_tiles == 1
+
+
+@pytest.fixture(scope="module")
+def halo_default():
+    """Frames and the derived-default session's ``halo`` output."""
+    frames = np.random.default_rng(2).random((2, 120, 16, 3), np.float32)
+    session = engine.SRSession(LAYERS, vertical_policy="halo")
+    return frames, np.asarray(session.upscale(frames))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("band_rows", legal_band_rows(120))
+def test_halo_output_is_independent_of_band_rows(band_rows, halo_default):
+    """Under ``halo`` every legal band decomposition recomputes its
+    neighbours' rows exactly, so the output is the derived default's bit
+    for bit."""
+    frames, want = halo_default
+    got = engine.SRSession(LAYERS, vertical_policy="halo",
+                           band_rows=band_rows).upscale(frames)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_pipeline_depth_defaults_to_two_and_explicit_is_kept():
+    assert engine.SRSession(LAYERS).pipeline_depth == 2
+    session = engine.SRSession(LAYERS, pipeline_depth=3)
+    session.upscale(np.zeros((5, 12, 16, 3), np.float32))
+    assert session.pipeline_depth == 3
+
+
+def test_serving_writes_nothing_under_home(tmp_path, monkeypatch):
+    """The schedule is derived, never read from or written to a user
+    directory: serving leaves an empty HOME and XDG cache empty."""
+    monkeypatch.setenv("HOME", str(tmp_path))
+    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path))
+    frames = np.zeros((3, 12, 16, 3), np.float32)
+    engine.SRSession(LAYERS).upscale(frames)
+    with engine.SRServer.open("abpn_x3", layers=LAYERS) as server:
+        server.submit(frames).result()
+    assert os.listdir(tmp_path) == []
 
 
 def test_plan_from_request_derives_geometry():
@@ -167,16 +287,25 @@ def test_session_bucket_padding_parity():
         np.asarray(out3), np.asarray(engine.run(plan, LAYERS, frames)))
 
 
-def test_session_max_bucket_is_a_ceiling():
+@pytest.mark.parametrize("n,max_bucket,bucket,chunks", [
+    (8, 5, 4, 2),     # the cap clamps DOWN to a power of two
+    (3, None, 4, 1),  # no cap: the next power of two
+    (5, None, 8, 1),
+    (1, 4, 1, 1),
+    (6, 4, 4, 2),     # the tail keeps the first chunk's program
+    (9, 8, 8, 2),
+])
+def test_session_max_bucket_is_a_ceiling(n, max_bucket, bucket, chunks):
     """max_bucket is never exceeded: the bucket clamps DOWN to the largest
     power of two within the cap and larger requests chunk."""
-    session = engine.SRSession(LAYERS, backend="tilted", max_bucket=5)
-    frames = jax.random.uniform(jax.random.PRNGKey(8), (8, 12, 16, 3))
-    out = session.upscale(frames)  # bucket 4, two chunks
-    assert out.shape == (8, 36, 48, 3)
+    session = engine.SRSession(LAYERS, backend="tilted",
+                               max_bucket=max_bucket)
+    frames = jax.random.uniform(jax.random.PRNGKey(8), (n, 12, 16, 3))
+    out = session.upscale(frames)
+    assert out.shape == (n, 36, 48, 3)
     entries = session.cache_stats()["entries"]
-    assert [e["bucket"] for e in entries] == [4]
-    assert session.stats()["batches"] == 2
+    assert [e["bucket"] for e in entries] == [bucket]
+    assert session.stats()["batches"] == chunks
     plan = session.plan_for((12, 16, 3))
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray(engine.run(plan, LAYERS, frames)))

@@ -29,7 +29,7 @@ from repro.models.abpn import ABPNConfig, init_abpn
 
 layers = init_abpn(jax.random.PRNGKey(0), ABPNConfig(num_layers=3, feature_channels=8))
 kw = dict(layers=layers, band_rows=6, vertical_policy="zero", precision="fp32",
-          max_bucket=4, autotune="off")
+          max_bucket=4)
 mesh = SRServer.open("abpn_x3", mesh=(1, 4), **kw)
 one = SRServer.open("abpn_x3", **kw)
 rng = np.random.default_rng(5)
@@ -113,7 +113,7 @@ def test_shard_put_absent_on_a_single_device_session(mesh_run):
 def test_single_device_assembly_keeps_the_default_device():
     layers = init_abpn(jax.random.PRNGKey(0), ABPNConfig(num_layers=3, feature_channels=8))
     server = SRServer.open("abpn_x3", layers=layers, band_rows=6, vertical_policy="zero",
-                           max_bucket=4, autotune="off")
+                           max_bucket=4)
     frames = np.random.default_rng(1).random((3, 24, 16, 3), dtype=np.float32)
     server.submit(frames).result()
     session = server.session()

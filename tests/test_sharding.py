@@ -235,26 +235,21 @@ def test_router_rejects_unknown_policy():
 # Session-level mesh validation (topology-independent paths)
 # ----------------------------------------------------------------------
 def test_session_trivial_mesh_is_unsharded():
-    s = SRSession(LAYERS, mesh=(1, 1), autotune="off")
+    s = SRSession(LAYERS, mesh=(1, 1))
     assert s.mesh_spec is None and s._router is None
     assert s.sharding_stats() is None
 
 
-def test_session_rejects_full_autotune_on_mesh():
-    with pytest.raises(ValueError):
-        SRSession(LAYERS, mesh=(1, 2), autotune="full")
-
-
 def test_session_rejects_bogus_mesh():
     with pytest.raises(ValueError):
-        SRSession(LAYERS, mesh="2x4", autotune="off")
+        SRSession(LAYERS, mesh="2x4")
 
 
 @pytest.mark.skipif(jax.device_count() != 1,
                     reason="needs a single-device interpreter")
 def test_session_mesh_needs_devices():
     with pytest.raises(ValueError, match="devices"):
-        SRSession(LAYERS, mesh=(1, 2), autotune="off")
+        SRSession(LAYERS, mesh=(1, 2))
 
 
 # ----------------------------------------------------------------------
@@ -302,8 +297,8 @@ def test_sharded_executor_rejects_mismatched_mesh():
 
 @needs_devices
 def test_session_serving_bit_exact_and_routed():
-    base = SRSession(LAYERS, vertical_policy="halo", autotune="off")
-    sharded = SRSession(LAYERS, vertical_policy="halo", autotune="off",
+    base = SRSession(LAYERS, vertical_policy="halo")
+    sharded = SRSession(LAYERS, vertical_policy="halo",
                         mesh=(2, 4), route="round_robin")
     frames = np.asarray(jax.random.uniform(
         jax.random.PRNGKey(3), (2, 48, 16, 3), jnp.float32))
@@ -324,10 +319,10 @@ def test_session_auto_rebands_for_mesh():
     # height 48 defaults to one 48-row band; 2 band shards force 24.
     # halo policy so the re-banded output stays bit-identical (zero /
     # replicate boundaries legitimately depend on where the bands fall).
-    s = SRSession(LAYERS, vertical_policy="halo", autotune="off", mesh=(1, 2))
+    s = SRSession(LAYERS, vertical_policy="halo", mesh=(1, 2))
     plan = s.plan_for((48, 16, 3))
     assert plan.num_bands % 2 == 0
-    base = SRSession(LAYERS, vertical_policy="halo", autotune="off")
+    base = SRSession(LAYERS, vertical_policy="halo")
     frames = np.asarray(jax.random.uniform(
         jax.random.PRNGKey(9), (1, 48, 16, 3), jnp.float32))
     np.testing.assert_array_equal(np.asarray(s.upscale(frames)),
@@ -336,7 +331,7 @@ def test_session_auto_rebands_for_mesh():
 
 @needs_devices
 def test_session_rejects_unshardable_explicit_band_rows():
-    s = SRSession(LAYERS, autotune="off", mesh=(1, 2), band_rows=48)
+    s = SRSession(LAYERS, mesh=(1, 2), band_rows=48)
     with pytest.raises(ValueError):
         s.plan_for((48, 16, 3))
 
@@ -385,8 +380,8 @@ def test_replica_routing_subprocess(subproc):
 
         layers = init_abpn(jax.random.PRNGKey(0),
                            ABPNConfig(num_layers=3, feature_channels=8))
-        base = SRSession(layers, vertical_policy="halo", autotune="off")
-        sharded = SRSession(layers, vertical_policy="halo", autotune="off",
+        base = SRSession(layers, vertical_policy="halo")
+        sharded = SRSession(layers, vertical_policy="halo",
                             mesh=(2, 2), route="least_loaded")
         frames = np.asarray(jax.random.uniform(
             jax.random.PRNGKey(3), (2, 24, 16, 3), jnp.float32))

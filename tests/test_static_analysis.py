@@ -130,22 +130,22 @@ def test_on_chip_budget_kb_exported():
 # Degenerate plans: surfaced, counted, never fatal
 # ----------------------------------------------------------------------
 def test_degenerate_plans_counted_and_warned():
-    session = engine.SRSession(LAYERS, autotune="off")
+    session = engine.SRSession(LAYERS)
     with pytest.warns(RuntimeWarning, match="ONE 127-row band"):
         plan = session.plan_for((127, 16, 3))  # prime height: fallback
     assert plan.degenerate_bands
-    assert session.tuning_stats()["degenerate_plans"] == 1
+    assert session.cache_stats()["degenerate_plans"] == 1
     findings = plan.verify()
     assert errors(findings) == []  # legal, just undesirable
     assert "degenerate_bands" in rules(findings)
     # a second shape with a fine decomposition does not count
     session.plan_for((120, 16, 3))
-    assert session.tuning_stats()["degenerate_plans"] == 1
+    assert session.cache_stats()["degenerate_plans"] == 1
 
 
 def test_strict_session_rejects_illegal_plan_before_compile():
     session = engine.SRSession(
-        LAYERS, backend="kernel", band_rows=120, strict=True, autotune="off"
+        LAYERS, backend="kernel", band_rows=120, strict=True
     )
     with pytest.raises(PlanVerificationError, match="on_chip_budget"):
         session.plan_for((360, 64, 3))
@@ -153,13 +153,13 @@ def test_strict_session_rejects_illegal_plan_before_compile():
 
 
 def test_strict_session_serves_legal_plans():
-    session = engine.SRSession(LAYERS, strict=True, autotune="off")
+    session = engine.SRSession(LAYERS, strict=True)
     hr = session.upscale(np.zeros(LR, np.float32))
     assert hr.shape == (36, 48, 3)
 
 
 def test_open_accepts_strict():
-    session = engine.SRSession.open("abpn_x3", strict=True, autotune="off")
+    session = engine.SRSession.open("abpn_x3", strict=True)
     assert session.strict
 
 
@@ -167,7 +167,7 @@ def test_open_accepts_strict():
 # Program audit: clean sessions + seeded violations
 # ----------------------------------------------------------------------
 def test_audit_clean_session():
-    session = engine.SRSession(LAYERS, autotune="off")
+    session = engine.SRSession(LAYERS)
     session.upscale(np.zeros(LR, np.float32))
     assert program_audit.audit_session(session) == []
 
@@ -201,13 +201,13 @@ def test_audit_catches_fp32_upcast():
 
 
 def test_real_bf16_program_has_no_upcast():
-    session = engine.SRSession(LAYERS, precision="bf16", autotune="off")
+    session = engine.SRSession(LAYERS, precision="bf16")
     session.upscale(np.zeros(LR, np.float32))
     assert program_audit.audit_session(session) == []
 
 
 def test_audit_catches_missing_donation():
-    session = engine.SRSession(LAYERS, donate_frames=True, autotune="off")
+    session = engine.SRSession(LAYERS, donate_frames=True)
     session.upscale(np.zeros(LR, np.float32))
     findings = program_audit.audit_session(session)
     if jax.default_backend() == "cpu":
@@ -224,7 +224,7 @@ def test_audit_catches_missing_donation():
 
 
 def test_recompile_detection():
-    session = engine.SRSession(LAYERS, cache_capacity=1, autotune="off")
+    session = engine.SRSession(LAYERS, cache_capacity=1)
     plan = session.plan_for(LR)
     session.serve_batch(plan, jnp.zeros((1, *LR)))
     session.serve_batch(plan, jnp.zeros((2, *LR)))  # evicts bucket 1

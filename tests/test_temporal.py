@@ -50,7 +50,6 @@ FRAME = RNG.random(LR, dtype=np.float32)
 def make_session(**kw):
     kw.setdefault("backend", "tilted")
     kw.setdefault("band_rows", BAND_ROWS)
-    kw.setdefault("autotune", "off")
     return engine.SRSession(LAYERS, **kw)
 
 
@@ -359,7 +358,7 @@ def test_delta_session_kernel_backend_bit_exact(policy):
     cfg = ABPNConfig(num_layers=3)
     layers = init_abpn(jax.random.PRNGKey(4), cfg)
     session = engine.SRSession(layers, backend="kernel", band_rows=6,
-                               vertical_policy=policy, autotune="off")
+                               vertical_policy=policy)
     clip = [FRAME.copy(), FRAME.copy(), clip_with_motion(3)[2]]
     with DeltaSession(session) as ds:
         for frame in clip:
@@ -369,7 +368,7 @@ def test_delta_session_kernel_backend_bit_exact(policy):
 
 
 def test_delta_session_rejects_reference_backend():
-    session = engine.SRSession(LAYERS, backend="reference", autotune="off")
+    session = engine.SRSession(LAYERS, backend="reference")
     with pytest.raises(ValueError, match="banded backend"):
         DeltaSession(session)
     ref_plan = engine.make_plan(LAYERS, LR, band_rows=BAND_ROWS,
@@ -489,7 +488,7 @@ def test_delta_parity_on_mesh_session_subprocess(subproc):
         layers = init_abpn(jax.random.PRNGKey(2), ABPNConfig())
         session = engine.SRSession(
             layers, backend="tilted", vertical_policy="halo",
-            band_rows=6, mesh=(2, 2), autotune="off")
+            band_rows=6, mesh=(2, 2))
         rng = np.random.default_rng(7)
         base = rng.random((24, 16, 3), dtype=np.float32)
         moved = base.copy(); moved[12:14] += 0.25
