@@ -9,7 +9,10 @@ Three executors over the same 3x3-conv stack, cross-validated in tests:
   by parallelepipedal column tiles via ``lax.scan``; the scan carry is the
   overlap buffer (the functional analogue of the queue-addressed SRAM of
   §III-F).  Horizontally EXACT w.r.t. the reference — the whole point of the
-  tilt is that left/right boundary information is preserved.
+  tilt is that left/right boundary information is preserved.  A schedule of
+  ONE tile (the engine's default off the ``kernel`` backend) has no carry to
+  hand on, so it runs as the plain chain of per-band SAME 3x3 convolutions
+  it is equal to: no tilt offset, column mask or overlap concatenate.
 * :func:`run_banded` — full-image driver: vertical band partitioning with a
   configurable boundary policy (``zero`` = paper's block-conv rows,
   ``halo`` = exact recompute margins, ``replicate`` = edge padding).
@@ -105,7 +108,8 @@ def _conv_tile(f: jax.Array, layer: ConvLayer, row_pad: str) -> jax.Array:
     """3x3 conv of a (R, C+2, Ci) tile slab -> (R, C, Co).
 
     Columns are VALID (the slab already carries the +-1 column halo, courtesy
-    of the overlap buffer); rows are padded per the band policy.
+    of the overlap buffer, or a one-tile band's zero columns); rows are
+    padded per the band policy.
     """
     if row_pad == "zero":
         f = jnp.pad(f, ((1, 1), (0, 0), (0, 0)))
@@ -145,7 +149,15 @@ def tilted_fused_band(
       ``conv_stack_reference`` horizontally (rows differ only per band
       policy, which is the caller's concern — see :func:`run_banded`).
 
-    Implementation notes (mirrors the hardware):
+    One tile (``num_tiles == 1``, which ``tile_cols=None`` gives): the band
+    runs as a chain of 3x3 convolutions over exactly its W columns — rows
+    padded per ``row_pad``, columns by one zero each side, VALID conv, bias,
+    ReLU, and the ``row_valid`` re-zeroing after every layer.  That is what
+    the one-tile sweep computes, with the same taps per output; it holds no
+    tilt offset, phantom-column mask, overlap concatenate or final slice,
+    so consecutive convolutions keep one layout.
+
+    Implementation notes for several tiles (mirrors the hardware):
       * the scan carry is the overlap buffer, shape ``(L, R, 2, Chmax)`` —
         feature index 0 is the *input* stream (so only C fresh input columns
         stream per tile, the source of the DRAM-bandwidth reduction);
@@ -161,6 +173,19 @@ def tilted_fused_band(
         raise ValueError("tile_cols must be >= 2 (overlap hand-off is 2 columns)")
     sched = make_schedule(width=W, tile_cols=tile_cols, num_layers=L)
     K, C = sched.num_tiles, tile_cols
+    if row_valid is not None:
+        row_mask = (jnp.arange(R) >= row_valid[0]) & (jnp.arange(R) < row_valid[1])
+    else:
+        row_mask = None
+
+    if K == 1:
+        f = x
+        for layer in layers:
+            f = _conv_tile(jnp.pad(f, ((0, 0), (1, 1), (0, 0))), layer, row_pad)
+            if row_mask is not None:
+                f = jnp.where(row_mask[:, None, None], f, 0)
+        return f
+
     chmax = max_channels(layers)
     dtype = x.dtype
 
@@ -176,10 +201,6 @@ def tilted_fused_band(
     overlap0 = overlap0.at[0, :, 1, :C0].set(x[:, 0, :])
 
     col_idx = jnp.arange(C)
-    if row_valid is not None:
-        row_mask = (jnp.arange(R) >= row_valid[0]) & (jnp.arange(R) < row_valid[1])
-    else:
-        row_mask = None
 
     def tile_step(overlap, inputs):
         k, fresh = inputs

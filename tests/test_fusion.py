@@ -15,7 +15,7 @@ from repro.core.fusion import (
     run_banded,
     tilted_fused_band,
 )
-from repro.core.tiling import make_schedule
+from repro.core.tiling import make_schedule, single_tile_cols
 
 
 def make_layers(key, channels, bias_scale=0.1):
@@ -67,16 +67,23 @@ def test_band_exactness_property(one_tile, width, tile_cols, depth, ch, rows):
     np.testing.assert_allclose(np.asarray(ref), np.asarray(til), atol=1e-5)
 
 
+@pytest.mark.parametrize("width,tile_cols", [(44, 8), (45, 8), (22, 8), (6, 3)],
+                         ids=["w44", "w45", "w22", "w6_c3"])
 @pytest.mark.parametrize("policy", ["zero", "replicate", "halo"])
-def test_one_tile_matches_eight_column_tiles(policy):
-    """The default one-tile sweep computes what the 8-column sweep does,
-    under every vertical policy."""
+def test_one_tile_matches_eight_column_tiles(policy, width, tile_cols):
+    """The default one-tile band (a plain SAME convolution chain) computes
+    bit for bit what the multi-tile scan does, under every vertical policy:
+    8-column tiles, or 3 columns below a width of 8.  W + L - 1 is 47, 48
+    and 25 for the 8-column widths, and 9 at W = 6."""
     layers = make_layers(jax.random.PRNGKey(11), [3, 8, 8, 8, 5])
-    img = jax.random.uniform(jax.random.PRNGKey(12), (60, 44, 3))
+    L = len(layers)
+    assert make_schedule(width, single_tile_cols(width, L), L).num_tiles == 1
+    assert make_schedule(width, tile_cols, L).num_tiles > 1
+    img = jax.random.uniform(jax.random.PRNGKey(12), (60, width, 3))
     one = run_banded(img, layers, band_rows=30, vertical_policy=policy)
-    eight = run_banded(img, layers, band_rows=30, tile_cols=8,
+    tiles = run_banded(img, layers, band_rows=30, tile_cols=tile_cols,
                        vertical_policy=policy)
-    np.testing.assert_allclose(np.asarray(one), np.asarray(eight), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(tiles))
 
 
 @pytest.mark.slow

@@ -127,14 +127,30 @@ def test_serving_epilogue_stays_lane_dense(one_chip, backend, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < EPILOGUE_TEMP_BOUND
 
 
-@pytest.mark.parametrize("height,width,bucket", [(360, W, 4), (180, 2 * W, 1)],
-                         ids=["640x360_bucket4", "4k_shard_bucket1"])
-def test_tilted_serving_program_has_no_scan_loop(one_chip, height, width, bucket):
+@pytest.mark.parametrize(
+    "height,width,bucket,bytes_bound",
+    [(360, W, 4, 4.0e9), (180, 2 * W, 1, 2.2e9), (360, W, 1, 3.2e9)],
+    ids=["640x360_bucket4", "4k_shard_bucket1", "640x360_bucket1"])
+def test_tilted_serving_program_has_no_scan_loop(one_chip, height, width,
+                                                 bucket, bytes_bound):
     """The tilted backend sweeps each band in one tile, so its serving
     program holds no ``while`` (an 8-column sweep is an 81-step scan at
     640 columns, 161 at 1280), and its temporaries stay lane-dense.  The
     second shape is one chip's shard of the 4K band-sharded cell: 3 bands
-    of 60 rows, 1280 columns."""
+    of 60 rows, 1280 columns.
+
+    A one-tile band is a plain chain of SAME convolutions over its W
+    columns: the program holds no column mask of the tilted width
+    (``pred[648]``, ``pred[1288]``; 47 at 640x360 bucket 1 when the tilt's
+    offsets and masks were kept), and the bytes it accesses stay about 10%
+    under each bound: 3.64 GB at 640x360 bucket 4, 2.01 GB on the 4K shard
+    at bucket 1, 2.92 GB at 640x360 bucket 1 (4.69, 4.16 and 3.97 GB with
+    the tilt's bookkeeping kept)."""
     compiled = _serving_program(one_chip, "tilted", height, width, bucket)
-    assert re.search(r"\bwhile\(", compiled.as_text()) is None
+    text = compiled.as_text()
+    assert re.search(r"\bwhile\(", text) is None
     assert compiled.memory_analysis().temp_size_in_bytes < EPILOGUE_TEMP_BOUND
+    assert re.search(r"pred\[(648|1288)\]", text) is None
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < bytes_bound
